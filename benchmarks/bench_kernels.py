@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import ops, ref
 
@@ -69,46 +68,6 @@ def run(gram_shapes=((4096, 16), (65536, 16), (65536, 64)),
     return rows
 
 
-def run_segment_view_sweep(
-    shape=(262144, 8, 2048),
-    budgets=(None, 1 << 19, 1 << 17, 1 << 15),
-    repeats: int = 5,
-) -> list:
-    """How ``segment_view``'s group chunking (the VMEM-budget spill path)
-    costs on wall time: each halving of the budget multiplies the number of
-    passes over the N input rows, so chunked runs bound the TPU worst case
-    where ``num_groups * (k+2)^2`` overflows the accumulator budget."""
-    m, k, g = shape
-    rng = np.random.default_rng(0)
-    c = jnp.asarray(rng.standard_normal(m).astype(np.float32))
-    x = jnp.asarray(rng.standard_normal(m).astype(np.float32))
-    l = jnp.asarray(rng.standard_normal((m, k)).astype(np.float32))
-    q = jnp.asarray(rng.standard_normal((m, k, k)).astype(np.float32))
-    seg = jnp.asarray(rng.integers(0, g, m).astype(np.int32))
-    rows = []
-    group_bytes = (k + 2) * (k + 2) * 4
-    for budget in budgets:
-        eff = min(budget or ops.VMEM_ACC_BYTES, ops.VMEM_ACC_BYTES)
-        g_chunk = max(1, min(g, eff // group_bytes - 1))
-        t = timeit(
-            lambda b=budget: ops.segment_view(
-                c, x, l, q, seg, g, degree=2, vmem_budget=b
-            ),
-            repeats=repeats,
-        )
-        rows.append(
-            {
-                "op": "segment_view",
-                "shape": f"{m}x{k}x{g}",
-                "vmem_budget": "default" if budget is None else budget,
-                "chunks": -(-g // g_chunk),
-                "sec": t,
-            }
-        )
-    emit("segment_view_chunks", rows)
-    return rows
-
-
 def main(smoke: bool = False) -> None:
     if smoke:
         run(
@@ -116,12 +75,8 @@ def main(smoke: bool = False) -> None:
             seg_shapes=((4096, 16, 16),),
             attn_shapes=((2, 256, 64),),
         )
-        run_segment_view_sweep(
-            shape=(8192, 4, 128), budgets=(None, 1 << 14), repeats=3
-        )
     else:
         run()
-        run_segment_view_sweep()
 
 
 if __name__ == "__main__":

@@ -6,8 +6,9 @@ the fused ``segment_view`` dispatch (``FactorizedEngine._extend_and_group``)
 against the unfused pair (``_extend_with_feature`` + ``_aggregate_out``)
 on identical inputs, reporting ``node_fusion_speedup`` (compare.py-gated)
 plus the roofline accounting from ``launch.roofline.traversal_node_terms``:
-predicted bandwidth-bound speedup, achieved GB/s, and the achieved fraction
-of the memory bound.  ``traversal_end_to_end`` times whole ``cofactors()``
+predicted bandwidth-bound speedup, achieved GB/s, and — on a chip with
+published peaks only — the achieved fraction of the memory bound.
+``traversal_end_to_end`` times whole ``cofactors()``
 traversals over the paper's Figure-1 schema at scale with the node kernels
 on vs off.
 
@@ -28,6 +29,7 @@ from repro.core.relation import Relation
 from repro.core.store import Store
 from repro.core.variable_order import VariableOrder
 from repro.data.synthetic import figure1_schema
+from repro.launch.mesh import HW
 from repro.launch.roofline import traversal_node_terms
 
 from .common import emit, timeit
@@ -105,8 +107,15 @@ def run_nodes(shapes=NODE_SHAPES, repeats: int = 5) -> list:
                 "fused_s": t_f,
                 "node_fusion_speedup": t_u / t_f,
                 "predicted_speedup": terms.predicted_speedup,
+                "device_kind": terms.device_kind,
                 "achieved_gbs": terms.achieved_gbs(t_f),
-                "bw_bound_fraction": terms.achieved_fraction(t_f),
+                # a share of the HBM bound exists only on a chip with
+                # published peaks; anywhere else it was not measured
+                "bw_bound_fraction": (
+                    terms.achieved_fraction(t_f)
+                    if terms.device_kind in HW
+                    else None
+                ),
             }
         )
     emit("traversal_nodes", rows)

@@ -146,4 +146,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     sys.exit(main())

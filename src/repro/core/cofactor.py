@@ -73,7 +73,8 @@ def design_matrix(
 @jax.jit
 def _gram_jnp(x):
     ones = jnp.ones((x.shape[0],), dtype=x.dtype)
-    return x.T @ x, x.T @ ones
+    hi = jax.lax.Precision.HIGHEST  # f32 sums, not one bf16 pass on a TPU
+    return jnp.matmul(x.T, x, precision=hi), jnp.matmul(x.T, ones, precision=hi)
 
 
 def cofactors_from_matrix(

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from .categorical import CatCofactors, SparseCounts, cat_cofactors_from_arrays
 from .factorize import Cofactors
 
@@ -56,9 +55,31 @@ __all__ = [
 ]
 
 
+ROW_BLOCK = 4096  # rows per f32 partial sum in _dot_t
+
+
+def _dot_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``aᵀ @ b`` over the rows of one shard, in f32.  The products take
+    ``Precision.HIGHEST`` (a TPU otherwise multiplies f32 operands in one
+    bf16 pass, about three significant digits), and the rows are summed in
+    blocks of ``ROW_BLOCK`` whose partials are added at the end: a single
+    accumulator running down millions of rows loses digits with every
+    addition.  Zero rows pad the last block; they add nothing."""
+    rows = a.shape[0]
+    if rows <= ROW_BLOCK:
+        return jnp.matmul(a.T, b, precision=jax.lax.Precision.HIGHEST)
+    pad = ((0, (-rows) % ROW_BLOCK), (0, 0))
+    a = jnp.pad(a, pad).reshape(-1, ROW_BLOCK, a.shape[1])
+    b = jnp.pad(b, pad).reshape(-1, ROW_BLOCK, b.shape[1])
+    parts = jnp.einsum(
+        "nrk,nrl->nkl", a, b, precision=jax.lax.Precision.HIGHEST
+    )
+    return parts.sum(axis=0)
+
+
 def _gram_local(z: jnp.ndarray) -> jnp.ndarray:
     """Local Gram of one shard; fp32 accumulation."""
-    return z.T @ z
+    return _dot_t(z, z)
 
 
 def sharded_gram(z: jnp.ndarray, mesh: Mesh, data_axes: Sequence[str]):
@@ -70,7 +91,7 @@ def sharded_gram(z: jnp.ndarray, mesh: Mesh, data_axes: Sequence[str]):
     axes = tuple(data_axes)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(axes, None),
         out_specs=P(),  # replicated result
@@ -213,7 +234,7 @@ def sharded_cat_cofactors(
     offs = np.concatenate([[0], np.cumsum(doms)]).astype(int)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axes, None), P(axes, None)),
         out_specs=(P(), P(), P()),
@@ -230,9 +251,9 @@ def sharded_cat_cofactors(
             ],
             axis=1,
         )  # [rows, ΣD] — n_cat ones per (unpadded) row
-        gram = u_local.T @ u_local
-        hu = hot.T @ u_local  # every [D_c, 1+k] block, one matmul
-        hh = hot.T @ hot  # every cat×cat block, one matmul
+        gram = _dot_t(u_local, u_local)
+        hu = _dot_t(hot, u_local)  # every [D_c, 1+k] block, one matmul
+        hh = _dot_t(hot, hot)  # every cat×cat block, one matmul
         return (
             jax.lax.psum(gram, axes),
             jax.lax.psum(hu, axes),

@@ -1067,7 +1067,7 @@ class FactorizedEngine:
         x = self._feature_values(view, attr)
         drop = set() if attr in keep else {attr}
         remaining = sorted(set(view.keys) - drop)
-        seg, num, keys = self._group_ids(view, remaining)
+        seg, num, keys, order = self._group_ids(view, remaining)
         c, l, q = kernel_ops.segment_view(
             view.c,
             x,
@@ -1076,6 +1076,7 @@ class FactorizedEngine:
             seg,
             num,
             degree=degree,
+            order=order,
         )
         return _View(
             keys=keys,
@@ -1088,8 +1089,10 @@ class FactorizedEngine:
 
     def _group_ids(
         self, view: _View, remaining: Sequence[str]
-    ) -> Tuple[np.ndarray, int, Dict[str, np.ndarray]]:
-        """Segment ids + surviving key columns for GROUP BY ``remaining``.
+    ) -> Tuple[np.ndarray, int, Dict[str, np.ndarray], Optional[object]]:
+        """Segment ids + surviving key columns for GROUP BY ``remaining``,
+        plus the stable sort order of the ids when the device computed it
+        (``None`` otherwise) — the node kernels' row order.
 
         Group numbering is canonical — ascending packed-key order over the
         (sorted) ``remaining`` attributes — whichever path computes it: the
@@ -1098,15 +1101,16 @@ class FactorizedEngine:
         host round-trip of the row ids."""
         n = view.num_rows
         if not remaining:
-            return np.zeros((n,), dtype=np.int32), 1, {}
+            return np.zeros((n,), dtype=np.int32), 1, {}, None
         doms = [self.domains[a] for a in remaining]
         # group_key, not composite_key: a view keyed by many wide
         # attributes (fact tables with ≫8 categorical keys) overflows
         # the strict mixed-radix product, and a GROUP BY only needs
         # within-call injectivity.
         key = group_key([view.keys[a] for a in remaining], doms)
+        order = None
         if self.device_grouping and n > 0:
-            seg, num, first = kernel_ops.group_ids_device(key)
+            seg, num, first, order = kernel_ops.group_ids_device(key)
         else:
             uniq, first, inv = np.unique(
                 key, return_index=True, return_inverse=True
@@ -1114,7 +1118,7 @@ class FactorizedEngine:
             seg = inv.astype(np.int32)
             num = len(uniq)
         keys = {a: view.keys[a][first] for a in remaining}
-        return seg, num, keys
+        return seg, num, keys, order
 
     def _group_rows(
         self, view: _View, remaining: Sequence[str], degree: int
@@ -1122,7 +1126,7 @@ class FactorizedEngine:
         """GROUP BY ``remaining`` over a view's rows (segment-sum of every
         block) — the aggregation core shared by :meth:`_aggregate_out` and
         the delta-fold :meth:`_merge_views`."""
-        seg, num, keys = self._group_ids(view, remaining)
+        seg, num, keys, order = self._group_ids(view, remaining)
         if self.use_node_kernels and view.num_rows > 0:
             # one multi-block kernel call instead of a scatter per block
             c, l, q = kernel_ops.segment_blocks(
@@ -1132,6 +1136,7 @@ class FactorizedEngine:
                 seg,
                 num,
                 degree=degree,
+                order=order,
             )
         else:
             c = self._segment_sum(view.c, seg, num)

@@ -63,6 +63,12 @@ class GDResult:
         return self.theta[:-1]
 
 
+def _mv(a: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """Matrix-vector product at full f32 precision (a TPU otherwise takes
+    one bf16 pass, which caps the gradient at about three digits)."""
+    return jnp.matmul(a, v, precision=jax.lax.Precision.HIGHEST)
+
+
 def _run_loop(step_fn, theta0, cfg: GDConfig):
     """Shared while_loop driver.  Carry: (θ, α, prev_sum, it, converged)."""
 
@@ -101,7 +107,7 @@ def _bgd_cofactor_jit(cof: jnp.ndarray, trainable: jnp.ndarray, cfg: GDConfig):
     theta0 = jnp.zeros((p,), dtype=cfg.dtype).at[-1].set(-1.0)
 
     def step(theta, alpha):
-        s = cof @ theta  # the whole data scan, collapsed to one matvec
+        s = _mv(cof, theta)  # the whole data scan, collapsed to one matvec
         return alpha * (s + cfg.ridge * theta) * trainable
 
     return _run_loop(step, theta0, cfg)
@@ -115,8 +121,8 @@ def _bgd_cofactor_penalty_jit(
     theta0 = jnp.zeros((p,), dtype=cfg.dtype).at[-1].set(-1.0)
 
     def step(theta, alpha):
-        s = cof @ theta
-        return alpha * (s + pen @ theta) * trainable
+        s = _mv(cof, theta)
+        return alpha * (s + _mv(pen, theta)) * trainable
 
     return _run_loop(step, theta0, cfg)
 
@@ -156,7 +162,7 @@ def _bgd_data_jit(z: jnp.ndarray, trainable: jnp.ndarray, cfg: GDConfig):
     theta0 = jnp.zeros((p,), dtype=cfg.dtype).at[-1].set(-1.0)
 
     def step(theta, alpha):
-        s = z.T @ (z @ theta)  # full data scan, every iteration (noPre)
+        s = _mv(z.T, _mv(z, theta))  # full data scan, every iteration (noPre)
         return alpha * (s + cfg.ridge * theta) * trainable
 
     return _run_loop(step, theta0, cfg)

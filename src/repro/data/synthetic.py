@@ -162,12 +162,12 @@ def favorita_like(
     cluster = rng.integers(1, 6, size=n_stores).astype(np.float64)
     perishable = rng.integers(0, 2, size=n_items).astype(np.float64)
     dcoil = np.cumsum(rng.normal(0, 1, size=n_dates)) + 50.0
-    transactions_rows = []
-    for d in dates:
-        for s in stores_ids:
-            transactions_rows.append(
-                (int(d), int(s), float(rng.integers(500, 3000)))
-            )
+    # one Transactions row per (date, store), date-major
+    t_date = np.repeat(dates, n_stores)
+    t_store = np.tile(stores_ids, n_dates)
+    t_count = rng.integers(500, 3000, size=n_dates * n_stores).astype(
+        np.float64
+    )
 
     # fact table: a random subset of (date, store, item)
     total = n_dates * n_stores * n_items
@@ -197,11 +197,8 @@ def favorita_like(
             ),
             Relation.from_columns(
                 "Transactions",
-                {
-                    "date": [r[0] for r in transactions_rows],
-                    "store_nbr": [r[1] for r in transactions_rows],
-                },
-                {"transactions": [r[2] for r in transactions_rows]},
+                {"date": t_date, "store_nbr": t_store},
+                {"transactions": t_count},
                 {"date": n_dates, "store_nbr": n_stores},
             ),
             Relation.from_columns(

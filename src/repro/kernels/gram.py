@@ -49,6 +49,8 @@ def _gram_kernel(x_i_ref, x_j_ref, out_ref):
         x_j,
         dimension_numbers=(((0,), (0,)), ((), ())),  # contract over rows
         preferred_element_type=jnp.float32,
+        # f32 operands would otherwise take one bf16 MXU pass
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

@@ -3,8 +3,10 @@
 Paper §4.2 runs two SQL aggregates (AVG, MAX(ABS)) per feature over the
 union of relations containing it.  A memory-bound op like this should touch
 HBM exactly once, so the kernel fuses both reductions into a single stream:
-each [bm, 1] block is reduced on the VPU and folded into two scalar
-accumulators held in VMEM across the 1-D grid.
+the column arrives lane-dense as ``[M/128, 128]``, and each ``[bm, 128]``
+block is folded on the VPU into two ``[8, 128]`` partial accumulators held
+in VMEM across the 1-D grid (vector stores only — a TPU kernel cannot store
+a scalar to VMEM).  The wrapper finishes the 1024 partials.
 
 Padding: the wrapper zero-pads to a block multiple — zeros do not change the
 sum, and max(|x|, 0) = max|x| since |·| ≥ 0.  The true element count is
@@ -21,7 +23,7 @@ from jax.experimental import pallas as pl
 
 __all__ = ["moments_kernel_call"]
 
-DEFAULT_BM = 1024
+DEFAULT_BM = 512  # rows of 128 lanes per block
 
 
 def _moments_kernel(x_ref, sum_ref, max_ref):
@@ -32,31 +34,30 @@ def _moments_kernel(x_ref, sum_ref, max_ref):
         sum_ref[...] = jnp.zeros_like(sum_ref)
         max_ref[...] = jnp.zeros_like(max_ref)
 
-    x = x_ref[...]  # [bm, 1]
-    sum_ref[0, 0] += jnp.sum(x)
-    max_ref[0, 0] = jnp.maximum(max_ref[0, 0], jnp.max(jnp.abs(x)))
+    x = x_ref[...].astype(jnp.float32)  # [bm, 128]
+    tiles = x.reshape(x.shape[0] // 8, 8, 128)  # sublane-tile aligned
+    sum_ref[...] += jnp.sum(tiles, axis=0)
+    max_ref[...] = jnp.maximum(max_ref[...], jnp.max(jnp.abs(tiles), axis=0))
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def moments_kernel_call(
     x: jnp.ndarray, bm: int = DEFAULT_BM, interpret: bool = True
 ):
-    """Raw pallas_call on a padded [M, 1] column (M % bm == 0).
-    Returns (sum [1,1], maxabs [1,1]) fp32.  Use ``ops.moments``."""
-    m, one = x.shape
-    assert one == 1 and m % bm == 0, x.shape
-    nm = m // bm
+    """Raw pallas_call on a padded lane-dense ``[M, 128]`` column block
+    (``M % bm == 0``, ``bm % 8 == 0``).  Returns ``(sum, maxabs)`` partials,
+    each f32 ``[8, 128]``.  Use ``ops.moments``."""
+    m, lanes = x.shape
+    assert lanes == 128 and m % bm == 0 and bm % 8 == 0, (x.shape, bm)
+    part = jax.ShapeDtypeStruct((8, 128), jnp.float32)
     return pl.pallas_call(
         _moments_kernel,
-        grid=(nm,),
-        in_specs=[pl.BlockSpec((bm, 1), lambda mm: (mm, 0))],
+        grid=(m // bm,),
+        in_specs=[pl.BlockSpec((bm, 128), lambda mm: (mm, 0))],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda mm: (0, 0)),
-            pl.BlockSpec((1, 1), lambda mm: (0, 0)),
+            pl.BlockSpec((8, 128), lambda mm: (0, 0)),
+            pl.BlockSpec((8, 128), lambda mm: (0, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
+        out_shape=[part, part],
         interpret=interpret,
     )(x)

@@ -6,10 +6,11 @@ does NOT import it.
 """
 
 from . import mesh, policy, roofline
-from .mesh import HW, make_host_mesh, make_production_mesh
+from .mesh import HW, hardware, make_host_mesh, make_production_mesh
 
 __all__ = [
     "HW",
+    "hardware",
     "make_host_mesh",
     "make_production_mesh",
     "mesh",

@@ -27,7 +27,7 @@ import json
 import re
 from typing import Dict, Optional
 
-from .mesh import HW
+from .mesh import TARGET_KIND, Hardware, hardware
 
 __all__ = [
     "RooflineTerms",
@@ -113,6 +113,12 @@ class RooflineTerms:
     #: calibrated semantics of cost_analysis on this backend (dryrun
     #: --calibrate): "per_shard" = numbers are already per device.
     flops_scope: str = "per_shard"
+    #: the chip whose published peaks the terms are taken against
+    device_kind: str = TARGET_KIND
+
+    @property
+    def hw(self) -> Hardware:
+        return hardware(self.device_kind)
 
     @property
     def _div(self) -> float:
@@ -133,16 +139,16 @@ class RooflineTerms:
     @property
     def t_compute(self) -> float:
         # == HLO_FLOPs_global / (chips × peak): evaluated per device
-        return self.flops_per_device / HW.peak_flops_bf16
+        return self.flops_per_device / self.hw.peak_flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HW.hbm_bw
+        return self.bytes_per_device / self.hw.hbm_bw
 
     @property
     def t_collective(self) -> float:
         # coll_bytes are already per-shard (post-SPMD shapes)
-        return self.coll_bytes / HW.ici_bw
+        return self.coll_bytes / self.hw.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -169,6 +175,7 @@ class RooflineTerms:
             "shape": self.shape,
             "mesh": self.mesh,
             "chips": self.chips,
+            "device_kind": self.device_kind,
             "hlo_flops": self.hlo_flops,
             "hlo_bytes": self.hlo_bytes,
             "coll_bytes": self.coll_bytes,
@@ -202,14 +209,20 @@ class TraversalNodeTerms:
     speedup (a pure byte ratio — both paths are bandwidth-bound, the
     FLOP/byte intensity is far below any machine balance point) comes
     from.  ``achieved_fraction(seconds)`` turns a measured node time into
-    the fraction of the HBM bandwidth bound the §Roofline table reports.
+    the fraction of the HBM bandwidth bound of ``device_kind`` — the chip
+    the time was measured on; a kind with no published peaks raises.
     """
 
     n_rows: int
     k: int
     num_groups: int
+    device_kind: str
     degree: int = 2
     dtype_bytes: int = 4
+
+    @property
+    def hw(self) -> Hardware:
+        return hardware(self.device_kind)
 
     def _block_elems(self, k: int) -> int:
         """Elements per row of (c, l[, q]) blocks with k features."""
@@ -256,11 +269,11 @@ class TraversalNodeTerms:
 
     @property
     def t_memory_fused(self) -> float:
-        return self.bytes_fused / HW.hbm_bw
+        return self.bytes_fused / self.hw.hbm_bw
 
     @property
     def t_memory_unfused(self) -> float:
-        return self.bytes_unfused / HW.hbm_bw
+        return self.bytes_unfused / self.hw.hbm_bw
 
     @property
     def predicted_speedup(self) -> float:
@@ -278,6 +291,7 @@ class TraversalNodeTerms:
         return {
             "n_rows": self.n_rows,
             "k": self.k,
+            "device_kind": self.device_kind,
             "num_groups": self.num_groups,
             "degree": self.degree,
             "dtype_bytes": self.dtype_bytes,
@@ -296,15 +310,22 @@ def traversal_node_terms(
     num_groups: int,
     degree: int = 2,
     dtype_bytes: int = 4,
+    device_kind: Optional[str] = None,
 ) -> TraversalNodeTerms:
     """Per-node traversal accounting for the §Roofline audit: bytes/FLOPs
-    of one fused extend-and-group node from its view shape and degree."""
+    of one fused extend-and-group node from its view shape and degree.
+    ``device_kind`` defaults to the first visible device's."""
     if degree not in (1, 2):
         raise ValueError(f"degree must be 1 or 2, got {degree}")
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
     return TraversalNodeTerms(
         n_rows=int(n_rows),
         k=int(k),
         num_groups=int(num_groups),
+        device_kind=device_kind,
         degree=int(degree),
         dtype_bytes=int(dtype_bytes),
     )

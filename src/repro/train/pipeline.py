@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..models import model as model_lib
 from ..models.layers import apply_norm
 from ..models.model import _apply_block  # same block code as the assembly
@@ -41,11 +40,7 @@ __all__ = ["pipeline_loss_fn", "make_pp_loss_for_mesh"]
 
 
 def _run_periods(params_periods, x, cfg, positions, vary=()):
-    """Apply this stage's stacked periods (scan, rematted like forward).
-
-    The aux accumulator is [1]-shaped, not scalar: rank-0 floats crossing
-    the shard_map linearization boundary break the pinned JAX's transpose
-    (scalar residuals get all-axes names; see ``_pvary``)."""
+    """Apply this stage's stacked periods (scan, rematted like forward)."""
     aux0 = _pvary(jnp.zeros((1,), jnp.float32), vary)
 
     def period_fn(carry, pp):
@@ -63,21 +58,10 @@ def _run_periods(params_periods, x, cfg, positions, vary=()):
 
 def _pvary(x, axes):
     """Mark a constant as varying over the manual axes (shard_map vma typing
-    requires scan carries to have consistent varying sets).  Older JAX (the
-    pinned 0.4.x) has no vma typing at all — there the marking is a no-op."""
+    requires scan carries to have consistent varying sets)."""
     if not axes:
         return x
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, tuple(axes))
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, tuple(axes), to="varying")  # newer spelling
-    # pre-vma JAX (pinned 0.4.x): no varying annotation exists.  Tie the
-    # constant to the manual axes with a zero-valued axis_index term so it
-    # enters the shard_map jaxpr as a device-dependent value rather than a
-    # captured constant — the old transpose machinery mishandles rank-0
-    # constant scan carries (_SpecError on the cotangent).
-    bump = sum(jax.lax.axis_index(a) for a in axes) * 0
-    return x + bump.astype(x.dtype)
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def pipeline_loss_fn(params, batch, cfg, *, stages: int, microbatches: int,
@@ -107,8 +91,7 @@ def pipeline_loss_fn(params, batch, cfg, *, stages: int, microbatches: int,
             logits, jnp.maximum(labels_mb, 0)[..., None], axis=-1
         )[..., 0]
         mask = (labels_mb >= 0).astype(jnp.float32)
-        # [1]-shaped sums — keep every float accumulator rank ≥ 1 inside the
-        # shard_map body (scalar residuals break the pinned JAX transpose)
+        # [1]-shaped sums, like every float accumulator of the shard_map body
         return (
             jnp.sum((logz - tgt) * mask).reshape(1),
             jnp.sum(mask).reshape(1),
@@ -199,7 +182,7 @@ def make_pp_loss_for_mesh(cfg, mesh: Mesh, policy, batch_abs,
     batch_specs_ = jax.tree.map(lambda s: s.spec, batch_sh)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(param_specs, batch_specs_),
         out_specs=P(),

@@ -86,10 +86,10 @@ def test_segment_gram_group_chunking():
 
 @pytest.mark.parametrize("budget", [40, 100, 200])
 def test_segment_gram_forced_chunking_matches_unchunked(budget):
-    """Drive the g_chunk < num_groups branch directly with a tiny VMEM
-    budget: the rebased-id chunked result must match the one-shot path
-    and the oracle."""
-    m, k, g = 57, 3, 10  # k*k*4 = 36 bytes/group: budget 40 -> 1 grp/chunk
+    """A tiny VMEM budget forces the smallest tiles, so the staircase
+    walks several (row block, group tile) visits: the result must match
+    the default tiles and the oracle."""
+    m, k, g = 57, 3, 10
     x = rand((m, k), jnp.float32)
     seg = jax.random.randint(KEY, (m,), 0, g)
     chunked = ops.segment_gram(x, seg, g, vmem_budget=budget)
@@ -279,10 +279,10 @@ def test_segment_view_k0_padding(impl):
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("budget", [100, 400, 1000])
 def test_segment_view_forced_chunking(impl, budget):
-    """A tiny vmem_budget drives the rebased-id group-chunking branch —
-    same numbers as the one-shot path and the oracle (mirrors
+    """A tiny vmem_budget forces the smallest tiles — same numbers as the
+    default tiles and the oracle (mirrors
     test_segment_gram_forced_chunking_matches_unchunked)."""
-    m, k, g = 157, 3, 11  # (k+2)^2*4 = 100 B/group: budget 100 -> chunked
+    m, k, g = 157, 3, 11
     c, x, l, q, seg = _sv_inputs(m, k, g)
     chunked = ops.segment_view(
         c, x, l, q, seg, g, degree=2, impl=impl, vmem_budget=budget
@@ -335,9 +335,7 @@ def test_segment_view_fp64_xla():
     """Under x64 the fused XLA path accumulates in fp64 and matches the
     fp64 oracle bit-for-bit-scale (1e-15 rel), preserving the numpy-oracle
     comparisons the engine's property tests rely on."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(3)
         m, k, g = 200, 3, 7
         c = jnp.asarray(rng.standard_normal(m))
@@ -398,14 +396,71 @@ def test_group_ids_device_matches_np_unique():
     for n, dom in [(1, 1), (37, 5), (500, 40), (64, 64)]:
         key = rng.integers(0, dom, n).astype(np.int64)
         uniq, first, inv = np.unique(key, return_index=True, return_inverse=True)
-        seg, num, dfirst = ops.group_ids_device(key)
+        seg, num, dfirst, order = ops.group_ids_device(key)
         assert num == len(uniq)
         np.testing.assert_array_equal(np.asarray(seg), inv.astype(np.int32))
+        np.testing.assert_array_equal(
+            np.asarray(order), np.argsort(key, kind="stable")
+        )
         np.testing.assert_array_equal(key[dfirst], uniq)
         # ties resolve to identical gather targets: same key values
         np.testing.assert_array_equal(key[dfirst], key[first])
 
 
 def test_group_ids_device_empty():
-    seg, num, first = ops.group_ids_device(np.zeros((0,), np.int64))
+    seg, num, first, order = ops.group_ids_device(np.zeros((0,), np.int64))
     assert num == 0 and seg.shape == (0,) and first.shape == (0,)
+    assert order.shape == (0,)
+
+
+def test_group_ids_device_exact_for_wide_codes():
+    """Group codes past int32 (``relation.group_key`` packs several wide
+    attributes into int64) sort as two 32-bit halves: the grouping equals
+    ``np.unique`` exactly, and codes that differ only above bit 31 never
+    merge (``2**32 + 5`` and ``5`` are two groups)."""
+    rng = np.random.default_rng(11)
+    base = np.array(
+        [2**32 + 5, 5, 7, 2**31, 2**31 - 1, -3, 2**61 + 5, 2**61 + 2**32 + 5],
+        np.int64,
+    )
+    for key in (
+        base[:3],
+        np.concatenate(
+            [base, rng.choice(base, 50), rng.integers(0, 2**62, 200)]
+        ),
+    ):
+        uniq, first, inv = np.unique(
+            key, return_index=True, return_inverse=True
+        )
+        seg, num, dfirst, order = ops.group_ids_device(key)
+        assert num == len(uniq)
+        np.testing.assert_array_equal(np.asarray(seg), inv.astype(np.int32))
+        np.testing.assert_array_equal(
+            np.asarray(order), np.argsort(key, kind="stable")
+        )
+        np.testing.assert_array_equal(dfirst, first)
+
+
+@pytest.mark.parametrize("degree", [1, 2])
+def test_segment_view_groups_match_rows(degree):
+    """G ≈ N — a fact-table leaf, nearly every row its own group — through
+    the Pallas kernel in interpret mode, with the smallest tiles so the
+    staircase walks many (row block, group tile) visits; with and without
+    the caller's sort order it equals ``segment_view_ref``."""
+    m, k, g = 3000, 2, 2900
+    c, x, l, q, _ = _sv_inputs(m, k, g)
+    rng = np.random.default_rng(5)
+    seg = jnp.asarray(
+        rng.permutation(
+            np.concatenate([np.arange(g), rng.integers(0, g, m - g)])
+        ).astype(np.int32)
+    )
+    q = q if degree == 2 else None
+    expect = ref.segment_view_ref(c, x, l, q, seg, g, degree=degree)
+    for order in (None, jnp.argsort(seg, stable=True)):
+        got = ops.segment_view(
+            c, x, l, q, seg, g, degree=degree, impl="pallas",
+            vmem_budget=1, order=order,
+        )
+        _assert_view_eq(got, expect)
+

@@ -142,6 +142,41 @@ def test_fused_device_grouping_matches_host():
     )
 
 
+def test_device_grouping_exact_for_wide_group_codes():
+    """Forced device grouping over views whose GROUP BY codes pass 2³²:
+    keys ``a`` (70,000 values) and ``b`` (65,536 values) pack to
+    ``a·65536 + b``, so rows ``i`` and ``i + 65536`` share ``b`` and their
+    codes differ by exactly 2³² — one group if the codes were cut to
+    int32.  ``a`` is a feature above that GROUP BY, so a merge would drop
+    a row's ``a`` value; the fused engine must equal the fp64 oracle."""
+    from repro.core.relation import group_key
+    from repro.core.variable_order import VariableOrder
+
+    n, nb = 70_000, 65_536
+    rel = Relation.from_columns(
+        "R",
+        {"a": np.arange(n), "b": np.arange(n) % nb},
+        {"x": np.random.default_rng(3).standard_normal(n)},
+        {"a": n, "b": nb},
+    )
+    store = Store([rel])
+    leaf = VariableOrder("x", [VariableOrder.leaf("R")])
+    vorder = VariableOrder.intercept(
+        [VariableOrder("a", [VariableOrder("b", [leaf])])]
+    )
+    codes = group_key([rel.keys["a"], rel.keys["b"]], [n, nb])
+    assert codes[nb] - codes[0] == 2**32  # equal once cut to int32
+    fused = FactorizedEngine(
+        store, vorder, ["a", "x"], backend="jax", use_view_cache=False
+    )
+    fused.device_grouping = True
+    oracle = FactorizedEngine(store, vorder, ["a", "x"], backend="numpy")
+    np.testing.assert_allclose(
+        fused.cofactors().matrix(), oracle.cofactors().matrix(),
+        rtol=1e-4,  # f32 sums of a² reach 1e14; a merge errs by ~10%
+    )
+
+
 def test_default_on_for_jax_backend_only():
     b = figure1_schema()
     cols = b.features + [b.label]

@@ -3,7 +3,7 @@
 import numpy as np
 
 from repro.configs import ARCHS, SHAPES
-from repro.launch.mesh import HW
+from repro.launch.mesh import hardware
 from repro.launch.roofline import (
     RooflineTerms,
     collective_bytes,
@@ -81,9 +81,23 @@ def test_model_flops_shapes():
 
 
 def test_hw_constants_match_assignment():
-    assert HW.peak_flops_bf16 == 197e12
-    assert HW.hbm_bw == 819e9
-    assert HW.ici_bw == 50e9
+    hw = hardware("TPU v5 lite")  # what JAX reports for a TPU v5e
+    assert hw.peak_flops_bf16 == 197e12
+    assert hw.hbm_bw == 819e9
+    assert hw.ici_bw == 50e9
+
+
+def test_unknown_device_kind_has_no_peaks():
+    import pytest
+
+    from repro.launch.roofline import traversal_node_terms
+
+    with pytest.raises(ValueError, match="no published peaks"):
+        hardware("cpu")
+    t = traversal_node_terms(64, 2, 4, device_kind="cpu")
+    assert t.bytes_fused > 0  # shape accounting needs no peaks
+    with pytest.raises(ValueError):
+        t.achieved_fraction(1.0)
 
 
 def test_traversal_node_terms_math():
@@ -119,12 +133,12 @@ def test_traversal_node_terms_degree1():
 def test_traversal_node_terms_achieved():
     from repro.launch.roofline import traversal_node_terms
 
-    t = traversal_node_terms(65536, 4, 256)
+    t = traversal_node_terms(65536, 4, 256, device_kind="TPU v5 lite")
     # at exactly the memory-bound time, the achieved fraction is 1.0 and
     # achieved bandwidth equals the HBM figure
     sec = t.t_memory_fused
     np.testing.assert_allclose(t.achieved_fraction(sec), 1.0)
-    np.testing.assert_allclose(t.achieved_gbs(sec) * 1e9, HW.hbm_bw)
+    np.testing.assert_allclose(t.achieved_gbs(sec) * 1e9, t.hw.hbm_bw)
     assert t.achieved_fraction(0.0) == 0.0
     j = t.to_json()
     assert j["predicted_speedup"] == t.predicted_speedup

@@ -10,6 +10,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import sharding as shd
 from repro.configs import get_config
+from repro.launch.mesh import make_host_mesh
 from repro.models import model
 from repro.train import TrainHParams, init_state
 
@@ -71,7 +72,7 @@ def test_rule_override():
 
 
 def _real_policy(rules=shd.TRAIN_RULES):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     return shd.ShardingPolicy(mesh, rules)
 
 
@@ -113,7 +114,7 @@ def test_constrain_noop_without_policy():
 
 
 def test_constrain_applies_on_real_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     pol = shd.ShardingPolicy(mesh, shd.TRAIN_RULES)
     with shd.use_policy(pol):
         y = jax.jit(lambda x: shd.constrain(x, ("batch", "seq")))(

@@ -110,10 +110,8 @@ def test_compressed_psum_matches_mean():
     from functools import partial
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data")),
     )
     def fn(gs, es):
