@@ -35,7 +35,6 @@ import argparse
 import collections
 import contextlib
 import dataclasses
-import functools
 import json
 import os
 import sys
@@ -144,43 +143,44 @@ def design(joined, cols, factors):
 
 
 class KernelProbe:
-    """Counts the engine's node-kernel and device-grouping dispatches while
-    active, and keeps the arguments of its largest fused-node call so the
-    program that call ran can be compiled again and inspected."""
-
-    NAMES = ("segment_view", "segment_blocks", "group_ids_device")
+    """The engine's node-kernel and device-grouping dispatches while active
+    (``calls``, from the program's own counters), and the arguments of its
+    largest fused-node call, so the program that call ran can be compiled
+    again and inspected."""
 
     def __init__(self):
         from repro.kernels import ops
 
         self.ops = ops
-        self.calls = collections.Counter()
         self.largest = None
-        self._orig = {n: getattr(ops, n) for n in self.NAMES}
+        self._sv = ops.segment_view
 
-    def _wrap(self, name):
-        fn = self._orig[name]
+    @staticmethod
+    def _dispatches() -> collections.Counter:
+        from repro import obs
 
-        @functools.wraps(fn)
-        def wrapped(*args, **kw):
-            self.calls[name] += 1
-            if name == "segment_view" and (
-                self.largest is None
-                or args[0].shape[0] > self.largest[0][0].shape[0]
-            ):
-                self.largest = (args, kw)
-            return fn(*args, **kw)
+        return collections.Counter(obs.snapshot()["dispatches"])
 
-        return wrapped
+    @property
+    def calls(self) -> collections.Counter:
+        """Dispatches by kernel since the probe was entered."""
+        end = self._dispatches() if self._end is None else self._end
+        return end - self._start
+
+    def _capture(self, *args, **kw):
+        """``ops.segment_view``, keeping the arguments of the largest call."""
+        if self.largest is None or args[0].shape[0] > self.largest[0][0].shape[0]:
+            self.largest = (args, kw)
+        return self._sv(*args, **kw)
 
     def __enter__(self):
-        for name in self.NAMES:
-            setattr(self.ops, name, self._wrap(name))
+        self._start, self._end = self._dispatches(), None
+        self.ops.segment_view = self._capture
         return self
 
     def __exit__(self, *exc):
-        for name, fn in self._orig.items():
-            setattr(self.ops, name, fn)
+        self.ops.segment_view = self._sv
+        self._end = self._dispatches()
         return False
 
     def node_program_text(self) -> str:
@@ -189,7 +189,7 @@ class KernelProbe:
 
         (c, x, l, q, seg, num), kw = self.largest
         degree, order = kw["degree"], kw.get("order")
-        sv = self._orig["segment_view"]
+        sv = self._sv
 
         def node(c, x, l, q, seg, order):
             return sv(c, x, l, q, seg, num, degree=degree, order=order)

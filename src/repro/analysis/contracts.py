@@ -99,6 +99,13 @@ LOCKS: Tuple[LockSpec, ...] = (
         reentrant=False,
         doc="Per-attribute dictionary extension lock (append-only encodings).",
     ),
+    LockSpec(
+        "obs._counter_lock",
+        "obs",
+        "_counter_lock",
+        reentrant=False,
+        doc="Process counters of repro.obs; leaf lock, taken under any other.",
+    ),
 )
 
 LOCKS_BY_NAME: Dict[str, LockSpec] = {spec.name: spec for spec in LOCKS}
@@ -119,12 +126,17 @@ ORDER: Dict[str, Tuple[str, ...]] = {
         "FactorizedService._lock",
         "FactorizedService._stats_lock",
         "Store._mutate_lock",
+        "obs._counter_lock",
     ),
-    "FactorizedService._lock": ("FactorizedService._stats_lock",),
-    "Store._mutate_lock": ("ViewCache._mu", "_AttrDict._mu"),
-    "FactorizedService._stats_lock": (),
-    "ViewCache._mu": (),
-    "_AttrDict._mu": (),
+    "FactorizedService._lock": (
+        "FactorizedService._stats_lock",
+        "obs._counter_lock",
+    ),
+    "Store._mutate_lock": ("ViewCache._mu", "_AttrDict._mu", "obs._counter_lock"),
+    "FactorizedService._stats_lock": ("obs._counter_lock",),
+    "ViewCache._mu": ("obs._counter_lock",),
+    "_AttrDict._mu": ("obs._counter_lock",),
+    "obs._counter_lock": (),
 }
 
 
@@ -203,6 +215,12 @@ GUARDS: Tuple[GuardSpec, ...] = (
               "Admission gate flag."),
     GuardSpec("_runtime", "FactorizedService._lock", "write", ("FactorizedService",),
               "Runtime handle; lock-free pointer reads are fine."),
+    GuardSpec("_queue_wait_s", "FactorizedService._lock", "full",
+              ("FactorizedService",), "Summed queue wait of popped reads."),
+    GuardSpec("_queue_waits", "FactorizedService._lock", "full",
+              ("FactorizedService",), "Reads popped into a cycle."),
+    GuardSpec("_queue_wait_max_s", "FactorizedService._lock", "full",
+              ("FactorizedService",), "Longest queue wait of a popped read."),
     GuardSpec("_shed", "FactorizedService._lock", "write", ("FactorizedService",),
               "Shed-oldest counter; read in cache_info without the lock."),
     GuardSpec("_tenants", "FactorizedService._stats_lock", "full",

@@ -78,6 +78,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..kernels import ops as kernel_ops
 from .api import StoreReads
 from .relation import Relation, group_key, join_keys, sort_merge_join
@@ -419,98 +420,99 @@ class FactorizedEngine:
         use_view_cache: Optional[bool] = None,
         use_node_kernels: Optional[bool] = None,
     ) -> None:
-        self.store = store
-        # lazy-maintenance read barrier: fold the pending-delta log of the
-        # covered relations BEFORE freezing the catalog, so this engine
-        # probes a warm, up-to-date view cache.  Delta engines (overrides)
-        # skip it — they ARE the drain's workers, and their overridden
-        # relations must keep their recorded pending state.
-        if not overrides:
-            flush = getattr(store, "flush", None)
-            if callable(flush):
-                flush(vorder.relations())
-        # freeze the catalog: all *data* reads (relations, encoded columns)
-        # go through an immutable snapshot, so a concurrent ``append`` /
-        # ``put`` on the live store can never corrupt an in-flight
-        # traversal — the engine observes bit-identical data whether or
-        # not a mutation lands mid-batch.  Counters, the view cache and
-        # vorder registration still route through ``self.store`` (the
-        # snapshot forwards them), keeping store totals authoritative.
-        snap = getattr(store, "snapshot", None)
-        self.data = snap() if callable(snap) else store
-        validate(vorder, self.data)
-        self.vorder = vorder
-        self.features = list(features)
-        if backend not in ("jax", "numpy"):
-            raise ValueError(f"unknown backend {backend}")
-        self.backend = backend
-        self.xp = jnp if backend == "jax" else np
-        self.dtype = dtype or (jnp.float32 if backend == "jax" else np.float64)
-        self.scale = scale
-        # fused per-node kernels (repro.kernels.segment_view): extend-with-
-        # feature + GROUP BY collapse into ONE dispatch per node, grouping
-        # runs device-side, and all blocks of a plain regroup share one
-        # segment-reduce call.  Default: on for the jax backend (Pallas on
-        # TPU, the jitted XLA fusion elsewhere); the numpy oracle backend
-        # never uses them.  Bit-compatible grouping (same ids, same group
-        # order) keeps fused and unfused views interchangeable in the
-        # shared cache.
-        if use_node_kernels is None:
-            use_node_kernels = backend == "jax"
-        self.use_node_kernels = bool(use_node_kernels) and backend == "jax"
-        # device-resident grouping only where the device sort wins (it
-        # loses to host np.unique on the XLA CPU backend); tests flip this
-        # attribute to exercise the device path anywhere.
-        self.device_grouping = (
-            self.use_node_kernels and kernel_ops.fast_device_grouping()
-        )
-        self.group_by = list(group_by)
-        # delta mode: relations replaced by their append delta — the engine
-        # evaluates the join with ``name`` swapped for ``overrides[name]``
-        # against the live store (shared dictionaries, shared view cache).
-        self.overrides = dict(overrides or {})
-        unknown = set(self.overrides) - set(vorder.relations())
-        if unknown:
-            raise ValueError(
-                f"overrides {sorted(unknown)} not in the variable order"
+        with obs.span("repro.engine.init"):
+            self.store = store
+            # lazy-maintenance read barrier: fold the pending-delta log of the
+            # covered relations BEFORE freezing the catalog, so this engine
+            # probes a warm, up-to-date view cache.  Delta engines (overrides)
+            # skip it — they ARE the drain's workers, and their overridden
+            # relations must keep their recorded pending state.
+            if not overrides:
+                flush = getattr(store, "flush", None)
+                if callable(flush):
+                    flush(vorder.relations())
+            # freeze the catalog: all *data* reads (relations, encoded columns)
+            # go through an immutable snapshot, so a concurrent ``append`` /
+            # ``put`` on the live store can never corrupt an in-flight
+            # traversal — the engine observes bit-identical data whether or
+            # not a mutation lands mid-batch.  Counters, the view cache and
+            # vorder registration still route through ``self.store`` (the
+            # snapshot forwards them), keeping store totals authoritative.
+            snap = getattr(store, "snapshot", None)
+            self.data = snap() if callable(snap) else store
+            validate(vorder, self.data)
+            self.vorder = vorder
+            self.features = list(features)
+            if backend not in ("jax", "numpy"):
+                raise ValueError(f"unknown backend {backend}")
+            self.backend = backend
+            self.xp = jnp if backend == "jax" else np
+            self.dtype = dtype or (jnp.float32 if backend == "jax" else np.float64)
+            self.scale = scale
+            # fused per-node kernels (repro.kernels.segment_view): extend-with-
+            # feature + GROUP BY collapse into ONE dispatch per node, grouping
+            # runs device-side, and all blocks of a plain regroup share one
+            # segment-reduce call.  Default: on for the jax backend (Pallas on
+            # TPU, the jitted XLA fusion elsewhere); the numpy oracle backend
+            # never uses them.  Bit-compatible grouping (same ids, same group
+            # order) keeps fused and unfused views interchangeable in the
+            # shared cache.
+            if use_node_kernels is None:
+                use_node_kernels = backend == "jax"
+            self.use_node_kernels = bool(use_node_kernels) and backend == "jax"
+            # device-resident grouping only where the device sort wins (it
+            # loses to host np.unique on the XLA CPU backend); tests flip this
+            # attribute to exercise the device path anywhere.
+            self.device_grouping = (
+                self.use_node_kernels and kernel_ops.fast_device_grouping()
             )
-        self.passes = 0
-        self.node_visits = 0
-        self.vc_hits = 0
-        self.vc_misses = 0
-        self._check_group_attrs(self.group_by)
-        self._index_nodes()
-        self._encode_attributes()
-        missing = set(self.group_by) - set(self.domains)
-        if missing:
-            raise ValueError(
-                f"group-by attributes {sorted(missing)} occur in no relation "
-                "of the variable order"
-            )
-        # persistent cross-batch view cache (store-owned).  Scaled engines
-        # opt out: their views bake engine-specific affine transforms in.
-        vc = getattr(store, "view_cache", None)
-        if use_view_cache is None:
-            use_view_cache = vc is not None and vc.enabled
-        self._vc = vc if (use_view_cache and vc is not None) else None
-        if scale is not None:
-            self._vc = None
-        self._vc_skip = frozenset(self.overrides)
-        # encoded columns are a SNAPSHOT of the catalog at construction
-        # time: if the store mutates afterwards, this engine's views are
-        # stale-by-design and must neither probe nor publish the shared
-        # cache (a stale publish would poison every later query).  The
-        # comparison is frozen-vs-live: ``live_version`` reaches through a
-        # StoreSnapshot to the parent store's current version.
-        self._vc_version = getattr(self.data, "version", 0)
-        if self._vc is not None and hasattr(store, "_register_vorder"):
-            # append maintenance needs the order to rebuild delta engines
-            store._register_vorder(self.sig, vorder)
-        self._leaf_memo: Dict[Tuple[str, int], _View] = {}
-        # shared delta-fold memo; degree safety comes from _execute's
-        # degree-aware acceptance (a low-degree view never serves a
-        # higher-degree fold), so folds at every degree share descents
-        self._maint_memo: Dict[Tuple[int, FrozenSet[str]], _View] = {}
+            self.group_by = list(group_by)
+            # delta mode: relations replaced by their append delta — the engine
+            # evaluates the join with ``name`` swapped for ``overrides[name]``
+            # against the live store (shared dictionaries, shared view cache).
+            self.overrides = dict(overrides or {})
+            unknown = set(self.overrides) - set(vorder.relations())
+            if unknown:
+                raise ValueError(
+                    f"overrides {sorted(unknown)} not in the variable order"
+                )
+            self.passes = 0
+            self.node_visits = 0
+            self.vc_hits = 0
+            self.vc_misses = 0
+            self._check_group_attrs(self.group_by)
+            self._index_nodes()
+            self._encode_attributes()
+            missing = set(self.group_by) - set(self.domains)
+            if missing:
+                raise ValueError(
+                    f"group-by attributes {sorted(missing)} occur in no relation "
+                    "of the variable order"
+                )
+            # persistent cross-batch view cache (store-owned).  Scaled engines
+            # opt out: their views bake engine-specific affine transforms in.
+            vc = getattr(store, "view_cache", None)
+            if use_view_cache is None:
+                use_view_cache = vc is not None and vc.enabled
+            self._vc = vc if (use_view_cache and vc is not None) else None
+            if scale is not None:
+                self._vc = None
+            self._vc_skip = frozenset(self.overrides)
+            # encoded columns are a SNAPSHOT of the catalog at construction
+            # time: if the store mutates afterwards, this engine's views are
+            # stale-by-design and must neither probe nor publish the shared
+            # cache (a stale publish would poison every later query).  The
+            # comparison is frozen-vs-live: ``live_version`` reaches through a
+            # StoreSnapshot to the parent store's current version.
+            self._vc_version = getattr(self.data, "version", 0)
+            if self._vc is not None and hasattr(store, "_register_vorder"):
+                # append maintenance needs the order to rebuild delta engines
+                store._register_vorder(self.sig, vorder)
+            self._leaf_memo: Dict[Tuple[str, int], _View] = {}
+            # shared delta-fold memo; degree safety comes from _execute's
+            # degree-aware acceptance (a low-degree view never serves a
+            # higher-degree fold), so folds at every degree share descents
+            self._maint_memo: Dict[Tuple[int, FrozenSet[str]], _View] = {}
 
     def _index_nodes(self) -> None:
         """Assign stable preorder indices and static subtree summaries —
@@ -753,54 +755,55 @@ class FactorizedEngine:
             store_visits = getattr(self.store, "node_visits", None)
             if store_visits is not None:
                 self.store.node_visits = store_visits + 1
-            if node.is_relation:
-                view = self._leaf_view(node.relation, degree)
-            else:
-                child_views = [
-                    self._execute(
-                        ch, keep & plan.subtree_vars[id(ch)], plan, cache
-                    )
-                    for ch in node.children
-                ]
-                view = child_views[0]
-                for other in child_views[1:]:
-                    view = self._combine(view, other, degree)
-                if node.name == INTERCEPT:
-                    if set(view.keys) != keep:
-                        extra = sorted(set(view.keys) - keep)
-                        raise AssertionError(
-                            f"attributes {extra} survive to the intercept — "
-                            "variable order misses nodes for them"
-                        )
-                    # canonical key layout: a multi-child intercept leaves
-                    # the root view in JOIN order (first-seen keys).  Every
-                    # other keyed view comes out of _group_rows in sorted-
-                    # key canonical order — regroup here too, so cached
-                    # views keep one layout and a delta fold (_merge_views,
-                    # which regroups over sorted keys) preserves it exactly.
-                    if keep and len(child_views) > 1:
-                        view = self._group_rows(
-                            view, sorted(view.keys), degree
-                        )
+            with obs.span("repro.engine.node", node=node.name, degree=degree):
+                if node.is_relation:
+                    view = self._leaf_view(node.relation, degree)
                 else:
-                    if (
-                        self.use_node_kernels
-                        and node.name in self.features
-                        and degree >= 1
-                        and view.num_rows > 0
-                    ):
-                        # fused node: extend + GROUP BY in one kernel pass
-                        view = self._extend_and_group(
-                            view, node.name, keep, degree
+                    child_views = [
+                        self._execute(
+                            ch, keep & plan.subtree_vars[id(ch)], plan, cache
                         )
-                    else:
-                        if node.name in self.features and degree >= 1:
-                            view = self._extend_with_feature(
-                                view, node.name, degree
+                        for ch in node.children
+                    ]
+                    view = child_views[0]
+                    for other in child_views[1:]:
+                        view = self._combine(view, other, degree)
+                    if node.name == INTERCEPT:
+                        if set(view.keys) != keep:
+                            extra = sorted(set(view.keys) - keep)
+                            raise AssertionError(
+                                f"attributes {extra} survive to the intercept — "
+                                "variable order misses nodes for them"
                             )
-                        view = self._aggregate_out(
-                            view, node.name, keep, degree
-                        )
+                        # canonical key layout: a multi-child intercept leaves
+                        # the root view in JOIN order (first-seen keys).  Every
+                        # other keyed view comes out of _group_rows in sorted-
+                        # key canonical order — regroup here too, so cached
+                        # views keep one layout and a delta fold (_merge_views,
+                        # which regroups over sorted keys) preserves it exactly.
+                        if keep and len(child_views) > 1:
+                            view = self._group_rows(
+                                view, sorted(view.keys), degree
+                            )
+                    else:
+                        if (
+                            self.use_node_kernels
+                            and node.name in self.features
+                            and degree >= 1
+                            and view.num_rows > 0
+                        ):
+                            # fused node: extend + GROUP BY in one kernel pass
+                            view = self._extend_and_group(
+                                view, node.name, keep, degree
+                            )
+                        else:
+                            if node.name in self.features and degree >= 1:
+                                view = self._extend_with_feature(
+                                    view, node.name, degree
+                                )
+                            view = self._aggregate_out(
+                                view, node.name, keep, degree
+                            )
             self._vc_put(node, keep, degree, view)
         cache[memo_key] = view
         return view
@@ -921,14 +924,14 @@ class FactorizedEngine:
             a: self.attr_values[a][np.asarray(view.keys[a])].astype(np.float64)
             for a in q.group_by
         }
-        count = np.asarray(view.c, dtype=np.float64)
+        count = obs.to_host(view.c, dtype=np.float64)
         lin = quad = None
         if q.degree >= 1:
             # the view may have been evaluated at a higher degree for a
             # sibling query — slice what this query declared it reads.
-            lin = np.asarray(view.l, dtype=np.float64)
+            lin = obs.to_host(view.l, dtype=np.float64)
         if q.degree == 2:
-            quad = np.asarray(view.q, dtype=np.float64)
+            quad = obs.to_host(view.q, dtype=np.float64)
         return AggregateBlock(
             keys=keys,
             count=count,
@@ -970,34 +973,41 @@ class FactorizedEngine:
         xp = self.xp
         shared = sorted(set(v1.keys) & set(v2.keys))
         if shared:
-            doms = [self.domains[a] for a in shared]
-            # hash-join fallback past the int64 radix limit (join_keys),
-            # mirroring group_key's escape hatch on the GROUP BY side.
-            k1, k2 = join_keys(
-                [v1.keys[a] for a in shared],
-                [v2.keys[a] for a in shared],
-                doms,
-            )
-            i1, i2 = sort_merge_join(k1, k2)
+            with obs.span(
+                "repro.engine.join", rows_left=v1.num_rows,
+                rows_right=v2.num_rows,
+            ):
+                doms = [self.domains[a] for a in shared]
+                # hash-join fallback past the int64 radix limit (join_keys),
+                # mirroring group_key's escape hatch on the GROUP BY side.
+                k1, k2 = join_keys(
+                    [v1.keys[a] for a in shared],
+                    [v2.keys[a] for a in shared],
+                    doms,
+                )
+                i1, i2 = sort_merge_join(k1, k2)
         else:  # cross product (e.g. under the intercept)
             n1, n2 = v1.num_rows, v2.num_rows
             i1 = np.repeat(np.arange(n1, dtype=np.int64), n2)
             i2 = np.tile(np.arange(n2, dtype=np.int64), n1)
-        keys = {a: c[i1] for a, c in v1.keys.items()}
-        for a, c in v2.keys.items():
-            if a not in keys:
-                keys[a] = c[i2]
-        c1 = xp.take(v1.c, i1, axis=0)
-        c2 = xp.take(v2.c, i2, axis=0)
+        with obs.span("repro.engine.gather", rows=len(i1)):
+            keys = {a: c[i1] for a, c in v1.keys.items()}
+            for a, c in v2.keys.items():
+                if a not in keys:
+                    keys[a] = c[i2]
+            c1 = xp.take(v1.c, self._up(i1), axis=0)
+            c2 = xp.take(v2.c, self._up(i2), axis=0)
+            if degree >= 1:
+                l1 = xp.take(v1.l, self._up(i1), axis=0)
+                l2 = xp.take(v2.l, self._up(i2), axis=0)
+            if degree == 2:
+                q1 = xp.take(v1.q, self._up(i1), axis=0)
+                q2 = xp.take(v2.q, self._up(i2), axis=0)
         c = c1 * c2
         l = q = None
         if degree >= 1:
-            l1 = xp.take(v1.l, i1, axis=0)
-            l2 = xp.take(v2.l, i2, axis=0)
             l = xp.concatenate([l1 * c2[:, None], c1[:, None] * l2], axis=1)
             if degree == 2:
-                q1 = xp.take(v1.q, i1, axis=0)
-                q2 = xp.take(v2.q, i2, axis=0)
                 cross = l1[:, :, None] * l2[:, None, :]
                 top = xp.concatenate([q1 * c2[:, None, None], cross], axis=2)
                 bot = xp.concatenate(
@@ -1007,16 +1017,23 @@ class FactorizedEngine:
         feats = v1.feats + v2.feats if degree >= 1 else []
         return _View(keys=keys, c=c, l=l, q=q, feats=feats, degree=degree)
 
+    def _up(self, a, dtype=None):
+        """A host array as the backend's array: a counted upload on jax."""
+        if self.backend == "jax":
+            return obs.to_device(a, dtype=dtype)
+        return np.asarray(a, dtype=dtype)
+
     def _feature_values(self, view: _View, attr: str):
         """Per-row (scaled) feature values for ``attr``, in backend dtype."""
         if attr not in view.keys:
             raise AssertionError(f"feature {attr} not present below its node")
-        vals = self.attr_values[attr].astype(np.float64)[
-            np.asarray(view.keys[attr])
-        ]
-        if self.scale is not None:
-            vals = self.scale.transform(attr, vals)
-        return self.xp.asarray(vals, dtype=self.dtype)
+        with obs.span("repro.engine.feature", rows=view.num_rows):
+            vals = self.attr_values[attr].astype(np.float64)[
+                np.asarray(view.keys[attr])
+            ]
+            if self.scale is not None:
+                vals = self.scale.transform(attr, vals)
+            return self._up(vals, self.dtype)
 
     def _extend_with_feature(self, view: _View, attr: str, degree: int) -> _View:
         xp = self.xp
@@ -1102,23 +1119,25 @@ class FactorizedEngine:
         n = view.num_rows
         if not remaining:
             return np.zeros((n,), dtype=np.int32), 1, {}, None
-        doms = [self.domains[a] for a in remaining]
-        # group_key, not composite_key: a view keyed by many wide
-        # attributes (fact tables with ≫8 categorical keys) overflows
-        # the strict mixed-radix product, and a GROUP BY only needs
-        # within-call injectivity.
-        key = group_key([view.keys[a] for a in remaining], doms)
-        order = None
-        if self.device_grouping and n > 0:
-            seg, num, first, order = kernel_ops.group_ids_device(key)
-        else:
-            uniq, first, inv = np.unique(
-                key, return_index=True, return_inverse=True
-            )
-            seg = inv.astype(np.int32)
-            num = len(uniq)
-        keys = {a: view.keys[a][first] for a in remaining}
-        return seg, num, keys, order
+        with obs.span("repro.engine.group", rows=n):
+            doms = [self.domains[a] for a in remaining]
+            # group_key, not composite_key: a view keyed by many wide
+            # attributes (fact tables with ≫8 categorical keys) overflows
+            # the strict mixed-radix product, and a GROUP BY only needs
+            # within-call injectivity.
+            with obs.span("repro.engine.group_key", rows=n):
+                key = group_key([view.keys[a] for a in remaining], doms)
+            order = None
+            if self.device_grouping and n > 0:
+                seg, num, first, order = kernel_ops.group_ids_device(key)
+            else:
+                uniq, first, inv = np.unique(
+                    key, return_index=True, return_inverse=True
+                )
+                seg = inv.astype(np.int32)
+                num = len(uniq)
+            keys = {a: view.keys[a][first] for a in remaining}
+            return seg, num, keys, order
 
     def _group_rows(
         self, view: _View, remaining: Sequence[str], degree: int
@@ -1225,7 +1244,7 @@ class FactorizedEngine:
             # allocation + scatter dispatch per block (the non-kernel
             # fallback; use_node_kernels batches all blocks in one call).
             return jax.ops.segment_sum(
-                jnp.asarray(data), jnp.asarray(seg), num_segments=num
+                obs.to_device(data), obs.to_device(seg), num_segments=num
             )
         out = np.zeros((num,) + data.shape[1:], dtype=data.dtype)
         np.add.at(out, seg, data)

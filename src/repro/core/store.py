@@ -103,6 +103,7 @@ from typing import (
 
 import numpy as np
 
+from .. import obs
 from .delta_log import DeltaLog
 from .fd import (
     FDReduction,
@@ -665,99 +666,100 @@ class Store:
         and every FD-reduced cache entry built under it is invalidated;
         new lhs ids with consistent rhs values extend the FD mappings.
         """
-        self._access("Store._relations", "write")
-        self._access("Store._delta_log", "write")
-        if name not in self._relations:
-            raise KeyError(f"append target {name!r} not in catalog")
-        base = self._relations[name]
-        merged = base.concat(delta)  # validates attribute sets first
+        with obs.span("repro.store.append", relation=name, rows=delta.num_rows):
+            self._access("Store._relations", "write")
+            self._access("Store._delta_log", "write")
+            if name not in self._relations:
+                raise KeyError(f"append target {name!r} not in catalog")
+            base = self._relations[name]
+            merged = base.concat(delta)  # validates attribute sets first
 
-        if not delta.num_rows:
-            # empty delta: publish the (identical) merged relation and bump
-            # the version WITHOUT moving the watermark — nothing about the
-            # data changed, so every cached entry stays valid.
+            if not delta.num_rows:
+                # empty delta: publish the (identical) merged relation and bump
+                # the version WITHOUT moving the watermark — nothing about the
+                # data changed, so every cached entry stays valid.
+                self._relations = {**self._relations, name: merged}
+                self.version += 1
+                return merged
+
+            delta_named = dataclasses.replace(
+                delta,
+                name=name,
+                keys=dict(delta.keys),
+                values=dict(delta.values),
+                domains=dict(delta.domains),
+            )
+            # FD check is a pure plan: raises on a declared-FD violation
+            # before anything below has mutated.
+            falsified, extensions = self._plan_fd_updates(delta_named)
+            if self.maintenance == "eager":
+                # fold-on-write, against the pre-merge catalog; stamped at the
+                # post-publish version so the entries are valid the moment the
+                # catalog lands.  A poisoned delta raises out of here with the
+                # store untouched (covering entries invalidated).
+                self._override_enc = {}
+                try:
+                    self._fold_relation(name, delta_named, {}, self.version + 1)
+                except Exception:
+                    self._invalidate(name)
+                    raise
+                finally:
+                    self._override_enc = None
+            # per-column moments: accumulate under union.  Eager in BOTH modes
+            # — the O(delta) column scan costs no more than the log push and
+            # keeps feature scaling off the drain path.  Built as a fresh map
+            # and published below with the catalog — a snapshot holding the
+            # old map never sees a partial update.
+            new_moments = dict(self._moments)
+            for attr, (s, mx, cnt) in list(self._moments.items()):
+                if attr not in delta_named.attributes:
+                    continue
+                col = delta_named.column(attr).astype(np.float64)
+                new_moments[attr] = (
+                    s + float(col.sum()),
+                    max(mx, float(np.abs(col).max())),
+                    cnt + len(col),
+                )
+            if falsified or extensions:
+                new_fds = dict(self._fds)
+                for key in falsified:
+                    del new_fds[key]
+                for key, mapping in extensions.items():
+                    new_fds[key] = dataclasses.replace(
+                        new_fds[key], mapping=mapping
+                    )
+                self._fds = new_fds
+                self._bump_fds()
+            if falsified:
+                self._invalidate_fd_entries()
+            # encoded-column cache: the merged relation is base ++ delta,
+            # so cached id columns extend with the delta's ids (global
+            # dictionaries grow append-only — existing ids never move).
+            new_enc = dict(self._enc_cols)
+            for attr in delta_named.attributes:
+                enc_key = (name, attr)
+                ids = new_enc.get(enc_key)
+                if ids is not None:
+                    delta_ids = self._dict_for(attr).extend_encode(
+                        delta_named.column(attr)
+                    )
+                    new_enc[enc_key] = np.concatenate([ids, delta_ids])
+            self._enc_cols = new_enc
+            self._moments = new_moments
+            # COW publish: snapshot readers holding the old maps are untouched.
             self._relations = {**self._relations, name: merged}
+            log = None
+            if self.maintenance == "lazy":
+                # metadata only: the stacked delta IS merged[base_rows:], so
+                # the log records row counts, never rows.
+                log = self._delta_log.record(
+                    name, base.num_rows, delta.num_rows, self.version
+                )
             self.version += 1
+            self._rel_versions[name] = self.version
+            if log is not None and self._should_compact(log):
+                self._compact(name)
             return merged
-
-        delta_named = dataclasses.replace(
-            delta,
-            name=name,
-            keys=dict(delta.keys),
-            values=dict(delta.values),
-            domains=dict(delta.domains),
-        )
-        # FD check is a pure plan: raises on a declared-FD violation
-        # before anything below has mutated.
-        falsified, extensions = self._plan_fd_updates(delta_named)
-        if self.maintenance == "eager":
-            # fold-on-write, against the pre-merge catalog; stamped at the
-            # post-publish version so the entries are valid the moment the
-            # catalog lands.  A poisoned delta raises out of here with the
-            # store untouched (covering entries invalidated).
-            self._override_enc = {}
-            try:
-                self._fold_relation(name, delta_named, {}, self.version + 1)
-            except Exception:
-                self._invalidate(name)
-                raise
-            finally:
-                self._override_enc = None
-        # per-column moments: accumulate under union.  Eager in BOTH modes
-        # — the O(delta) column scan costs no more than the log push and
-        # keeps feature scaling off the drain path.  Built as a fresh map
-        # and published below with the catalog — a snapshot holding the
-        # old map never sees a partial update.
-        new_moments = dict(self._moments)
-        for attr, (s, mx, cnt) in list(self._moments.items()):
-            if attr not in delta_named.attributes:
-                continue
-            col = delta_named.column(attr).astype(np.float64)
-            new_moments[attr] = (
-                s + float(col.sum()),
-                max(mx, float(np.abs(col).max())),
-                cnt + len(col),
-            )
-        if falsified or extensions:
-            new_fds = dict(self._fds)
-            for key in falsified:
-                del new_fds[key]
-            for key, mapping in extensions.items():
-                new_fds[key] = dataclasses.replace(
-                    new_fds[key], mapping=mapping
-                )
-            self._fds = new_fds
-            self._bump_fds()
-        if falsified:
-            self._invalidate_fd_entries()
-        # encoded-column cache: the merged relation is base ++ delta,
-        # so cached id columns extend with the delta's ids (global
-        # dictionaries grow append-only — existing ids never move).
-        new_enc = dict(self._enc_cols)
-        for attr in delta_named.attributes:
-            enc_key = (name, attr)
-            ids = new_enc.get(enc_key)
-            if ids is not None:
-                delta_ids = self._dict_for(attr).extend_encode(
-                    delta_named.column(attr)
-                )
-                new_enc[enc_key] = np.concatenate([ids, delta_ids])
-        self._enc_cols = new_enc
-        self._moments = new_moments
-        # COW publish: snapshot readers holding the old maps are untouched.
-        self._relations = {**self._relations, name: merged}
-        log = None
-        if self.maintenance == "lazy":
-            # metadata only: the stacked delta IS merged[base_rows:], so
-            # the log records row counts, never rows.
-            log = self._delta_log.record(
-                name, base.num_rows, delta.num_rows, self.version
-            )
-        self.version += 1
-        self._rel_versions[name] = self.version
-        if log is not None and self._should_compact(log):
-            self._compact(name)
-        return merged
 
     # -- lazy maintenance: pending-delta log + drain ---------------------------
     @_locked
@@ -802,35 +804,40 @@ class Store:
         """
         log = self._delta_log
         pend = log.items()
-        stats = {
-            "relations": len(pend),
-            "rows": log.total_rows(),
-            "appends": log.total_appends(),
-        }
-        self._draining = True
-        try:
-            for i, (name, rlog) in enumerate(pend):
-                # fresh memo per relation: the override slices below are
-                # keyed by object id, which a freed slice could recycle
-                self._override_enc = {}
-                delta = self._slice_rows(name, rlog.base_rows, None)
-                frozen = {
-                    later: self._slice_rows(later, 0, later_log.base_rows)
-                    for later, later_log in pend[i + 1 :]
-                }
-                self._fold_relation(name, delta, frozen, self.version)
-                log.clear(name, drained=True)
-        except Exception:
-            for name, _ in pend:
-                if name in log:
-                    self._invalidate(name)
-                    log.clear(name)
-            raise
-        finally:
-            self._draining = False
-            self._override_enc = None
-        log.drains += 1
-        return stats
+        with obs.span(
+            "repro.store.fold",
+            relation=tuple(name for name, _ in pend),
+            rows=log.total_rows(),
+        ):
+            stats = {
+                "relations": len(pend),
+                "rows": log.total_rows(),
+                "appends": log.total_appends(),
+            }
+            self._draining = True
+            try:
+                for i, (name, rlog) in enumerate(pend):
+                    # fresh memo per relation: the override slices below are
+                    # keyed by object id, which a freed slice could recycle
+                    self._override_enc = {}
+                    delta = self._slice_rows(name, rlog.base_rows, None)
+                    frozen = {
+                        later: self._slice_rows(later, 0, later_log.base_rows)
+                        for later, later_log in pend[i + 1 :]
+                    }
+                    self._fold_relation(name, delta, frozen, self.version)
+                    log.clear(name, drained=True)
+            except Exception:
+                for name, _ in pend:
+                    if name in log:
+                        self._invalidate(name)
+                        log.clear(name)
+                raise
+            finally:
+                self._draining = False
+                self._override_enc = None
+            log.drains += 1
+            return stats
 
     def _slice_rows(
         self, name: str, start: int, stop: Optional[int]

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .flash import DEFAULT_BK as FL_BK, DEFAULT_BQ as FL_BQ, flash_kernel_call
 from .gram import DEFAULT_BK, DEFAULT_BM, gram_kernel_call
 from .moments import DEFAULT_BM as MOM_BM, moments_kernel_call
@@ -136,9 +137,10 @@ def _sorted_reduce(seg, order, fields, num_groups, wp, wo, budget, call):
     m = seg.shape[0]
     groups = bucket(num_groups, 1)
     bm, bg = pick_tiles(m, groups, wp, wo, budget)
-    ids, payload = _sorted_payload(
-        seg, order, fields, wp=wp, mp=bucket(m, bm)
-    )
+    with obs.span("repro.kernel.pack", rows=m, width=wp):
+        ids, payload = _sorted_payload(
+            seg, order, fields, wp=wp, mp=bucket(m, bm)
+        )
     return call(ids, payload, groups, bm, bg)
 
 
@@ -343,31 +345,38 @@ def segment_view(
         impl = default_impl()
     if interpret is None:
         interpret = not on_tpu()
-    c, x, l = jnp.asarray(c), jnp.asarray(x), jnp.asarray(l)
-    q = jnp.asarray(q) if degree == 2 else None
-    seg = jnp.asarray(seg).astype(jnp.int32)
-    k = l.shape[1]
-    if impl == "pallas":
-        wp, wo = view_widths(k, degree)
-        out = _sorted_reduce(
-            seg,
-            order,
-            [c, x, l] + ([q] if degree == 2 else []),
-            num_groups,
-            wp,
-            wo,
-            _budget(vmem_budget),
-            lambda s, p, g, bm, bg: segment_view_kernel_call(
-                s, p, g, k, degree, bm=bm, bg=bg, interpret=interpret
-            ),
-        )
-        blocks = _view_blocks(out, num_groups=num_groups, k=k, degree=degree)
-        return tuple(b if b is None else b.astype(c.dtype) for b in blocks)
-    if degree == 1:
-        packed = _sv_xla_deg1(c, x, l, seg, num_groups).astype(c.dtype)
-        return packed[:, 0], packed[:, 1:], None
-    packed = _sv_xla_deg2(c, x, l, q, seg, num_groups).astype(c.dtype)
-    return packed[:, 0, 0], packed[:, 1:, 0], packed[:, 1:, 1:]
+    obs.dispatch("segment_view")
+    with obs.span(
+        "repro.kernel.segment_view", rows=c.shape[0], k=l.shape[1],
+        degree=degree, groups=num_groups,
+    ):
+        c, x, l = obs.to_device(c), obs.to_device(x), obs.to_device(l)
+        q = obs.to_device(q) if degree == 2 else None
+        seg = obs.to_device(seg).astype(jnp.int32)
+        k = l.shape[1]
+        if impl == "pallas":
+            wp, wo = view_widths(k, degree)
+            out = _sorted_reduce(
+                seg,
+                order,
+                [c, x, l] + ([q] if degree == 2 else []),
+                num_groups,
+                wp,
+                wo,
+                _budget(vmem_budget),
+                lambda s, p, g, bm, bg: segment_view_kernel_call(
+                    s, p, g, k, degree, bm=bm, bg=bg, interpret=interpret
+                ),
+            )
+            blocks = _view_blocks(
+                out, num_groups=num_groups, k=k, degree=degree
+            )
+            return tuple(b if b is None else b.astype(c.dtype) for b in blocks)
+        if degree == 1:
+            packed = _sv_xla_deg1(c, x, l, seg, num_groups).astype(c.dtype)
+            return packed[:, 0], packed[:, 1:], None
+        packed = _sv_xla_deg2(c, x, l, q, seg, num_groups).astype(c.dtype)
+        return packed[:, 0, 0], packed[:, 1:, 0], packed[:, 1:, 1:]
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "k", "degree"))
@@ -401,43 +410,48 @@ def segment_blocks(
         impl = default_impl()
     if interpret is None:
         interpret = not on_tpu()
-    c = jnp.asarray(c)
-    m = c.shape[0]
     k = l.shape[1] if degree >= 1 else 0
-    seg = jnp.asarray(seg).astype(jnp.int32)
-    if impl == "pallas":
-        fields = [c, l, q][: degree + 1]
-        wp = _round_up(1 + k + (k * k if degree == 2 else 0), SUBLANE)
-        out = _sorted_reduce(
-            seg,
-            order,
-            [jnp.asarray(f) for f in fields],
-            num_groups,
-            wp,
-            wp,
-            _budget(vmem_budget),
-            lambda s, p, g, bm, bg: segment_reduce_kernel_call(
-                s, p, g, bm=bm, bg=bg, interpret=interpret
-            ),
+    obs.dispatch("segment_blocks")
+    with obs.span(
+        "repro.kernel.segment_blocks", rows=c.shape[0], k=k, degree=degree,
+        groups=num_groups,
+    ):
+        c = obs.to_device(c)
+        m = c.shape[0]
+        seg = obs.to_device(seg).astype(jnp.int32)
+        if impl == "pallas":
+            fields = [c, l, q][: degree + 1]
+            wp = _round_up(1 + k + (k * k if degree == 2 else 0), SUBLANE)
+            out = _sorted_reduce(
+                seg,
+                order,
+                [obs.to_device(f) for f in fields],
+                num_groups,
+                wp,
+                wp,
+                _budget(vmem_budget),
+                lambda s, p, g, bm, bg: segment_reduce_kernel_call(
+                    s, p, g, bm=bm, bg=bg, interpret=interpret
+                ),
+            )
+            blocks = _reduced_blocks(
+                out, num_groups=num_groups, k=k, degree=degree
+            )
+            return tuple(b if b is None else b.astype(c.dtype) for b in blocks)
+        fields = [c[:, None]]
+        if degree >= 1:
+            fields.append(obs.to_device(l))
+        if degree == 2:
+            fields.append(obs.to_device(q).reshape(m, k * k))
+        out = jax.ops.segment_sum(
+            jnp.concatenate(fields, axis=1), seg, num_segments=num_groups
+        ).astype(c.dtype)
+        c_new = out[:, 0]
+        l_new = out[:, 1 : 1 + k] if degree >= 1 else None
+        q_new = (
+            out[:, 1 + k :].reshape(num_groups, k, k) if degree == 2 else None
         )
-        blocks = _reduced_blocks(
-            out, num_groups=num_groups, k=k, degree=degree
-        )
-        return tuple(b if b is None else b.astype(c.dtype) for b in blocks)
-    fields = [c[:, None]]
-    if degree >= 1:
-        fields.append(jnp.asarray(l))
-    if degree == 2:
-        fields.append(jnp.asarray(q).reshape(m, k * k))
-    out = jax.ops.segment_sum(
-        jnp.concatenate(fields, axis=1), seg, num_segments=num_groups
-    ).astype(c.dtype)
-    c_new = out[:, 0]
-    l_new = out[:, 1 : 1 + k] if degree >= 1 else None
-    q_new = (
-        out[:, 1 + k :].reshape(num_groups, k, k) if degree == 2 else None
-    )
-    return c_new, l_new, q_new
+        return c_new, l_new, q_new
 
 
 def fast_device_grouping() -> bool:
@@ -502,17 +516,19 @@ def group_ids_device(key) -> tuple:
     if n == 0:
         empty = jnp.zeros((0,), jnp.int32)
         return empty, 0, np.zeros((0,), np.int64), empty
-    size = bucket(n, 1024)
-    cols = [
-        jnp.asarray(np.pad(c, (0, size - n), constant_values=PAD_SEG))
-        for c in _key_columns(key)
-    ]
-    order = jnp.arange(size, dtype=jnp.int32)
-    for c in reversed(cols):
-        order = _sort_pass(jnp.take(c, order), order)
-    start, inv = _runs(n, order, *cols)
-    first = np.asarray(order)[np.asarray(start)].astype(np.int64)
-    return inv[:n], int(first.shape[0]), first, order[:n]
+    obs.dispatch("group_ids_device")
+    with obs.span("repro.kernel.group_ids", rows=n):
+        size = bucket(n, 1024)
+        cols = [
+            obs.to_device(np.pad(c, (0, size - n), constant_values=PAD_SEG))
+            for c in _key_columns(key)
+        ]
+        order = jnp.arange(size, dtype=jnp.int32)
+        for c in reversed(cols):
+            order = _sort_pass(jnp.take(c, order), order)
+        start, inv = _runs(n, order, *cols)
+        first = obs.to_host(order)[obs.to_host(start)].astype(np.int64)
+        return inv[:n], int(first.shape[0]), first, order[:n]
 
 
 def flash_attention(

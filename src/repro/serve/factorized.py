@@ -82,6 +82,7 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.factorize import (
     AggregateBlock,
     AggregateQuery,
@@ -248,6 +249,7 @@ class _Read:
     deadline: Optional[float] = None  # absolute time.monotonic()
     not_before: float = 0.0  # retry backoff stamp (monotonic)
     attempts: int = 0  # failed attempts so far
+    enqueued: float = 0.0  # when it (re)joined the queue (monotonic)
 
 
 @dataclasses.dataclass
@@ -356,6 +358,10 @@ class FactorizedService:
         self._retries = 0  # transient-fault requeues (service-wide)
         self._shed = 0  # tickets failed by shed_oldest backpressure
         self._fold_failures = 0  # idle-window folds that raised
+        # queue wait of the reads cycles took, enqueue to pop (queue lock)
+        self._queue_wait_s = 0.0
+        self._queue_waits = 0
+        self._queue_wait_max_s = 0.0
         # sanitizer seam (see Store.access_hook): when set, called as
         # hook("FactorizedService._reads", kind) at queue/stats touches.
         self.access_hook: Optional[Callable[[str, str], None]] = None
@@ -459,15 +465,16 @@ class FactorizedService:
     def append(self, tenant: str, name: str, delta: Relation) -> Ticket:
         """Queue a row append, applied after the current read window →
         the merged ``Relation``.  Visible to reads from the next cycle."""
-        with self._lock:
-            self._admit()
-            self._access("FactorizedService._writes", "write")
-            ticket = Ticket()
-            ticket._blocking = self._runtime is not None
-            self._writes.append(
-                _Write(tenant, name, delta, ticket, self._next_seq())
-            )
-        self._notify()
+        with obs.span("repro.service.submit", kind="append", tenant=tenant) as sp:
+            with self._lock:
+                self._admit()
+                self._access("FactorizedService._writes", "write")
+                ticket = Ticket()
+                ticket._blocking = self._runtime is not None
+                seq = self._next_seq()
+                self._writes.append(_Write(tenant, name, delta, ticket, seq))
+            sp.set(request=seq)
+            self._notify()
         return ticket
 
     def _submit_read(
@@ -486,26 +493,30 @@ class FactorizedService:
         abs_deadline = (
             time.monotonic() + deadline if deadline is not None else None
         )
-        with self._lock:
-            self._admit()
-            self._access("FactorizedService._reads", "write")
-            ticket = Ticket()
-            ticket._blocking = self._runtime is not None
-            self._reads.append(
-                _Read(
-                    tenant=tenant,
-                    kind=kind,
-                    vorder=vorder,
-                    features=features,
-                    queries=queries,
-                    backend=backend or self.backend,
-                    ticket=ticket,
-                    seq=self._next_seq(),
-                    deadline=abs_deadline,
-                    **extra,
+        with obs.span("repro.service.submit", kind=kind, tenant=tenant) as sp:
+            with self._lock:
+                self._admit()
+                self._access("FactorizedService._reads", "write")
+                ticket = Ticket()
+                ticket._blocking = self._runtime is not None
+                seq = self._next_seq()
+                self._reads.append(
+                    _Read(
+                        tenant=tenant,
+                        kind=kind,
+                        vorder=vorder,
+                        features=features,
+                        queries=queries,
+                        backend=backend or self.backend,
+                        ticket=ticket,
+                        seq=seq,
+                        deadline=abs_deadline,
+                        enqueued=time.monotonic(),
+                        **extra,
+                    )
                 )
-            )
-        self._notify()
+            sp.set(request=seq)
+            self._notify()
         return ticket
 
     def _admit(self) -> None:
@@ -594,68 +605,75 @@ class FactorizedService:
             return self._drain_cycle()
 
     def _drain_cycle(self) -> int:
-        now = time.monotonic()
-        expired: List[_Read] = []
-        reads: List[_Read] = []
-        with self._lock:
-            self._access("FactorizedService._reads", "write")
-            self._access("FactorizedService._writes", "write")
-            take = len(self._reads) if self.window is None else self.window
-            deferred: List[_Read] = []
-            while self._reads and len(reads) < take:
-                r = self._reads.popleft()
-                if r.deadline is not None and now >= r.deadline:
-                    expired.append(r)
-                elif r.not_before > now:
-                    deferred.append(r)  # retry backoff not elapsed yet
-                else:
-                    reads.append(r)
-            # deferred retries keep their queue position, in order
-            for r in reversed(deferred):
-                self._reads.appendleft(r)
-            writes = list(self._writes)
-            self._writes.clear()
-            self._not_full.notify_all()
+        with obs.span("repro.service.cycle") as sp:
+            now = time.monotonic()
+            expired: List[_Read] = []
+            reads: List[_Read] = []
+            with self._lock:
+                self._access("FactorizedService._reads", "write")
+                self._access("FactorizedService._writes", "write")
+                take = len(self._reads) if self.window is None else self.window
+                deferred: List[_Read] = []
+                popped = time.monotonic()
+                while self._reads and len(reads) < take:
+                    r = self._reads.popleft()
+                    if r.deadline is not None and now >= r.deadline:
+                        expired.append(r)
+                    elif r.not_before > now:
+                        deferred.append(r)  # retry backoff not elapsed yet
+                    else:
+                        reads.append(r)
+                        wait = popped - r.enqueued
+                        self._queue_wait_s += wait
+                        self._queue_waits += 1
+                        self._queue_wait_max_s = max(self._queue_wait_max_s, wait)
+                # deferred retries keep their queue position, in order
+                for r in reversed(deferred):
+                    self._reads.appendleft(r)
+                writes = list(self._writes)
+                self._writes.clear()
+                self._not_full.notify_all()
+            sp.set(reads=len(reads), writes=len(writes))
 
-        done = 0
-        # an expired deadline fails ITS ticket only — the rest of the
-        # window runs untouched
-        for r in expired:
-            self._fail_read(
-                r,
-                ServiceTimeout(
-                    f"deadline expired before service (tenant {r.tenant!r})"
-                ),
-                quarantine=False,
-            )
-            done += 1
-        # engine group = everything one traversal can legally share
-        groups: Dict[tuple, List[_Read]] = {}
-        for r in reads:
-            dt = np.dtype(r.dtype).name if r.dtype is not None else None
-            gkey = (r.vorder.signature(), r.backend, dt)
-            groups.setdefault(gkey, []).append(r)
-        for members in groups.values():
-            batches = (
-                [members] if self.coalesce else [[r] for r in members]
-            )
-            for batch in batches:
-                done += self._run_batch_group(batch)
+            done = 0
+            # an expired deadline fails ITS ticket only — the rest of the
+            # window runs untouched
+            for r in expired:
+                self._fail_read(
+                    r,
+                    ServiceTimeout(
+                        f"deadline expired before service (tenant {r.tenant!r})"
+                    ),
+                    quarantine=False,
+                )
+                done += 1
+            # engine group = everything one traversal can legally share
+            groups: Dict[tuple, List[_Read]] = {}
+            for r in reads:
+                dt = np.dtype(r.dtype).name if r.dtype is not None else None
+                gkey = (r.vorder.signature(), r.backend, dt)
+                groups.setdefault(gkey, []).append(r)
+            for members in groups.values():
+                batches = (
+                    [members] if self.coalesce else [[r] for r in members]
+                )
+                for batch in batches:
+                    done += self._run_batch_group(batch)
 
-        for w in writes:
-            self._apply_write(w)
-            done += 1
-        if writes:
-            self._access("FactorizedService._snapshot", "write")
-            self._snapshot = self.store.snapshot()
-        with self._lock:
-            idle = not self._reads
-        if self._writers_since_flush and (
-            self.flush_policy == "always"
-            or (self.flush_policy == "idle" and idle)
-        ):
-            self._flush_pending()
-        return done
+            for w in writes:
+                self._apply_write(w)
+                done += 1
+            if writes:
+                self._access("FactorizedService._snapshot", "write")
+                self._snapshot = self.store.snapshot()
+            with self._lock:
+                idle = not self._reads
+            if self._writers_since_flush and (
+                self.flush_policy == "always"
+                or (self.flush_policy == "idle" and idle)
+            ):
+                self._flush_pending()
+            return done
 
     def pending(self) -> int:
         """Queued (unserved) requests right now — reads plus writes."""
@@ -757,59 +775,64 @@ class FactorizedService:
 
     # -- internals -------------------------------------------------------------
     def _run_batch_group(self, batch: List[_Read]) -> int:
-        parts = [
-            BatchPart(rid=r.seq, features=r.features, queries=r.queries)
-            for r in batch
-        ]
-        # charge by store-level counter deltas, captured BEFORE engine
-        # construction: the engine's init is the lazy read barrier and may
-        # fold pending deltas, work that lands in store counters only.
-        store = self.store
-        vc = store.view_cache
-        before = (store.passes, store.node_visits, vc.hits, vc.misses, vc.bytes)
-        tenants = [r.tenant for r in batch]
-        self._access("FactorizedService._snapshot", "read")
-        try:
-            merged = merge_batches(parts)
-            first = batch[0]
-            dtype = np.dtype(first.dtype) if first.dtype is not None else None
-            engine = FactorizedEngine(
-                self._snapshot,
-                first.vorder,
-                merged.features,
-                backend=first.backend,
-                dtype=dtype,
-            )
-            results = engine.run_batch(merged.queries)
-            per_rid = scatter_results(merged, parts, results)
-        except Exception as err:
-            # whatever partial work happened is still real store work —
-            # charge it to this sub-batch before degrading
+        with obs.span(
+            "repro.service.batch", requests=tuple(r.seq for r in batch)
+        ):
+            parts = [
+                BatchPart(rid=r.seq, features=r.features, queries=r.queries)
+                for r in batch
+            ]
+            # charge by store-level counter deltas, captured BEFORE engine
+            # construction: the engine's init is the lazy read barrier and may
+            # fold pending deltas, work that lands in store counters only.
+            store = self.store
+            vc = store.view_cache
+            before = (store.passes, store.node_visits, vc.hits, vc.misses, vc.bytes)
+            tenants = [r.tenant for r in batch]
+            self._access("FactorizedService._snapshot", "read")
+            try:
+                merged = merge_batches(parts)
+                first = batch[0]
+                dtype = np.dtype(first.dtype) if first.dtype is not None else None
+                engine = FactorizedEngine(
+                    self._snapshot,
+                    first.vorder,
+                    merged.features,
+                    backend=first.backend,
+                    dtype=dtype,
+                )
+                results = engine.run_batch(merged.queries)
+                per_rid = scatter_results(merged, parts, results)
+            except Exception as err:
+                # whatever partial work happened is still real store work —
+                # charge it to this sub-batch before degrading
+                self._charge_store_delta(tenants, before)
+                if len(batch) > 1:
+                    # graceful degradation: bisect the window to isolate the
+                    # poisoned request — its co-riders must still get answers
+                    mid = len(batch) // 2
+                    return self._run_batch_group(
+                        batch[:mid]
+                    ) + self._run_batch_group(batch[mid:])
+                return self._fail_or_retry(batch[0], err)
             self._charge_store_delta(tenants, before)
             if len(batch) > 1:
-                # graceful degradation: bisect the window to isolate the
-                # poisoned request — its co-riders must still get answers
-                mid = len(batch) // 2
-                return self._run_batch_group(
-                    batch[:mid]
-                ) + self._run_batch_group(batch[mid:])
-            return self._fail_or_retry(batch[0], err)
-        self._charge_store_delta(tenants, before)
-        if len(batch) > 1:
-            self._batches += 1
-            self._coalesced_requests += len(batch)
-        for r in batch:
-            with self._stats_lock:
-                st = self._stats(r.tenant)
-                st.requests += 1
-                st.batches += 1
-            try:
-                r.ticket._resolve(self._finish(r, per_rid[r.seq]))
-            except Exception as err:
-                # per-request post-processing (solve/score) failed: the
-                # traversal was healthy, so no bisect/retry — just fail
-                self._fail_read(r, err, quarantine=False)
-        return len(batch)
+                self._batches += 1
+                self._coalesced_requests += len(batch)
+            for r in batch:
+                with self._stats_lock:
+                    st = self._stats(r.tenant)
+                    st.requests += 1
+                    st.batches += 1
+                try:
+                    with obs.span("repro.service.solve", request=r.seq):
+                        answer = self._finish(r, per_rid[r.seq])
+                    r.ticket._resolve(answer)
+                except Exception as err:
+                    # per-request post-processing (solve/score) failed: the
+                    # traversal was healthy, so no bisect/retry — just fail
+                    self._fail_read(r, err, quarantine=False)
+            return len(batch)
 
     def _fail_or_retry(self, r: _Read, err: BaseException) -> int:
         """A single isolated request failed.  Transient fault + retry
@@ -829,6 +852,7 @@ class FactorizedService:
                 self._stats(r.tenant).retries += 1
             self._retries += 1
             with self._lock:
+                r.enqueued = time.monotonic()
                 self._reads.append(r)
             self._notify()
             return 0
@@ -1003,11 +1027,15 @@ class FactorizedService:
                 self._access("FactorizedService._reads", "read")
                 info["queued_reads"] = len(self._reads)
                 info["queued_writes"] = len(self._writes)
+                info["queue_wait_s"] = self._queue_wait_s
+                info["queue_waits"] = self._queue_waits
+                info["queue_wait_max_s"] = self._queue_wait_max_s
             info["running"] = self.running
             info["retries"] = self._retries
             info["shed"] = self._shed
             info["fold_failures"] = self._fold_failures
             info["quarantined"] = len(self._quarantined)
+            info["process"] = obs.snapshot()
             return info
 
     def quarantined(self) -> List[Dict[str, object]]:
