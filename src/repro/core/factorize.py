@@ -1113,24 +1113,29 @@ class FactorizedEngine:
 
         Group numbering is canonical — ascending packed-key order over the
         (sorted) ``remaining`` attributes — whichever path computes it: the
-        host ``np.unique`` or the device sort (``kernel_ops.
-        group_ids_device``), which is bit-compatible and skips the per-node
-        host round-trip of the row ids."""
+        host ``np.unique`` over the packed ``group_key`` or the device
+        sort of the key columns (``kernel_ops.group_ids_device``), which
+        is bit-compatible and skips the per-node host round-trip of the
+        row ids."""
         n = view.num_rows
         if not remaining:
             return np.zeros((n,), dtype=np.int32), 1, {}, None
         with obs.span("repro.engine.group", rows=n):
             doms = [self.domains[a] for a in remaining]
-            # group_key, not composite_key: a view keyed by many wide
-            # attributes (fact tables with ≫8 categorical keys) overflows
-            # the strict mixed-radix product, and a GROUP BY only needs
-            # within-call injectivity.
-            with obs.span("repro.engine.group_key", rows=n):
-                key = group_key([view.keys[a] for a in remaining], doms)
+            cols = [view.keys[a] for a in remaining]
             order = None
             if self.device_grouping and n > 0:
-                seg, num, first, order = kernel_ops.group_ids_device(key)
+                seg, num, first, order = kernel_ops.group_ids_device(
+                    cols, doms
+                )
             else:
+                # group_key, not composite_key: a view keyed by many wide
+                # attributes (fact tables with ≫8 categorical keys)
+                # overflows the strict mixed-radix product, and a GROUP BY
+                # only needs within-call injectivity.
+                obs.grouped("host", n)
+                with obs.span("repro.engine.group_key", rows=n):
+                    key = group_key(cols, doms)
                 uniq, first, inv = np.unique(
                     key, return_index=True, return_inverse=True
                 )

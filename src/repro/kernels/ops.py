@@ -9,6 +9,7 @@ validation, per the repo's dry-run-first methodology).
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -485,48 +486,104 @@ def _runs(n, order, *cols):
     return start, inv
 
 
-def _key_columns(key: np.ndarray) -> list:
-    """int32 sort keys whose lexicographic order is the int64 codes' order
-    exactly: the codes themselves when they fit int32, else the signed high
-    half and the low half biased by 2³¹ (unsigned order as signed)."""
-    i32 = np.iinfo(np.int32)
-    if key.min() >= i32.min and key.max() <= i32.max:
-        return [key.astype(np.int32)]
-    return [
-        (key >> 32).astype(np.int32),
-        ((key & 0xFFFFFFFF) - 2**31).astype(np.int32),
-    ]
+def word_layout(domains) -> tuple:
+    """How many key columns each int32 sort word packs, most significant
+    word first: walking the columns from the most significant, a word
+    takes the next column while the product of its columns' domains stays
+    at or below ``PAD_SEG``, so its mixed-radix values stay below the
+    padding rows' ``PAD_SEG``.  Greedy filling gives the fewest words any
+    split of the columns in order can; a key whose domain product fits 31
+    bits is one word.  A key the int64 code would split into two halves
+    may take one pass more here (three 2¹⁶ domains: three words)."""
+    layout, size = [], None
+    for dom in domains:
+        dom = max(int(dom), 1)
+        if size is not None and size * dom <= PAD_SEG:
+            layout[-1] += 1
+            size *= dom
+        else:
+            layout.append(1)
+            size = dom
+    return tuple(layout)
 
 
-def group_ids_device(key) -> tuple:
-    """Device-resident GROUP BY ids: stable sort + adjacent-difference run
-    detection instead of host ``np.unique``.  ``key`` is the host int64
-    group code (``relation.group_key``); codes wider than int32 sort as two
-    32-bit halves (least significant pass first), so no code is ever
-    truncated.  Sizes are padded to a power of two with ``PAD_SEG`` keys,
-    which sort last.  Returns ``(seg, num_groups, first, order)``
-    bit-compatible with ``np.unique(key, return_index=True,
-    return_inverse=True)`` — groups numbered in ascending key order, and
-    ``first`` (host int array) the first occurrence of each group, ready to
-    gather host key columns.  ``seg`` and ``order`` (the stable sort
-    permutation) stay on device, feeding :func:`segment_view` /
-    :func:`segment_blocks` without a host round-trip of the per-row ids."""
-    key = np.asarray(key, dtype=np.int64)
-    n = key.shape[0]
+@functools.partial(jax.jit, static_argnames=("layout",))
+def _pack_words(n, radices, cols, *, layout):
+    """The sort words of :func:`word_layout`: each word's columns combined
+    mixed-radix (``w = w·d + col``, int32; ``radices`` the columns'
+    domains, traced, so a grown domain compiles nothing), ``PAD_SEG`` in
+    the rows at or past ``n``."""
+    pad = jnp.arange(cols[0].shape[0], dtype=jnp.int32) >= n
+    words, i = [], 0
+    for width in layout:
+        w = cols[i].astype(jnp.int32)
+        for j in range(i + 1, i + width):
+            w = w * radices[j] + cols[j].astype(jnp.int32)
+        words.append(jnp.where(pad, PAD_SEG, w))
+        i += width
+    return words
+
+
+def _padded(col: np.ndarray, dom: int, size: int) -> np.ndarray:
+    """``col`` zero-padded to ``size`` rows, in int16 where its domain
+    fits (the device widens it), else int32."""
+    dtype = np.int16 if dom <= np.iinfo(np.int16).max + 1 else np.int32
+    out = np.empty((size,), dtype)
+    out[: col.shape[0]] = col
+    out[col.shape[0] :] = 0
+    return out
+
+
+def group_ids_device(cols, domains) -> tuple:
+    """Device-resident GROUP BY ids over the encoded key columns ``cols``
+    (int ids in ``[0, domain)``, most significant first): stable LSD sort
+    passes + adjacent-difference run detection instead of host
+    ``np.unique``.  The host decides the word layout from ``domains``
+    alone (:func:`word_layout`) and pads each column to a power-of-two
+    size; the device packs the words and sorts them, least significant
+    word first, so no key is ever truncated.  Returns ``(seg, num_groups,
+    first, order)`` bit-compatible with ``np.unique(relation.group_key(
+    cols, domains), return_index=True, return_inverse=True)`` — groups
+    numbered in ascending tuple order, ``first`` (host int array) the
+    first occurrence of each group, ready to gather host key columns.
+    ``seg`` and ``order`` (the stable sort permutation) stay on device,
+    feeding :func:`segment_view` / :func:`segment_blocks` without a host
+    round-trip of the per-row ids."""
+    if any(int(d) > 2**31 for d in domains):
+        raise ValueError(f"key domains {list(domains)} exceed int32 ids")
+    n = int(np.shape(cols[0])[0])
     if n == 0:
         empty = jnp.zeros((0,), jnp.int32)
         return empty, 0, np.zeros((0,), np.int64), empty
     obs.dispatch("group_ids_device")
+    obs.grouped("device", n)
     with obs.span("repro.kernel.group_ids", rows=n):
         size = bucket(n, 1024)
-        cols = [
-            obs.to_device(np.pad(c, (0, size - n), constant_values=PAD_SEG))
-            for c in _key_columns(key)
-        ]
+        with obs.span("repro.engine.group_key", rows=n):
+            layout = word_layout(domains)
+            # numpy's casting copy releases the GIL, and first-touching
+            # fresh buffers is most of its cost: one thread per column
+            with ThreadPoolExecutor(len(cols)) as pool:
+                padded = list(
+                    pool.map(
+                        _padded,
+                        map(np.asarray, cols),
+                        map(int, domains),
+                        [size] * len(cols),
+                    )
+                )
+        # a domain past PAD_SEG only ever leads its word: its radix unused
+        radices = obs.to_device(
+            np.clip(np.asarray(domains, np.int64), 1, PAD_SEG).astype(np.int32)
+        )
+        words = _pack_words(
+            n, radices, [obs.to_device(c) for c in padded], layout=layout
+        )
         order = jnp.arange(size, dtype=jnp.int32)
-        for c in reversed(cols):
-            order = _sort_pass(jnp.take(c, order), order)
-        start, inv = _runs(n, order, *cols)
+        for i, w in enumerate(reversed(words)):
+            # the first pass sorts the words as they lie: no gather
+            order = _sort_pass(w if i == 0 else jnp.take(w, order), order)
+        start, inv = _runs(n, order, *words)
         first = obs.to_host(order)[obs.to_host(start)].astype(np.int64)
         return inv[:n], int(first.shape[0]), first, order[:n]
 

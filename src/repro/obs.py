@@ -18,6 +18,9 @@ The counters cover what no ``Store`` owns:
 - ``h2d_bytes`` / ``d2h_bytes``: bytes copied by :func:`to_device` /
   :func:`to_host`, the only counted copy sites;
 - ``dispatches``: calls of each node kernel and of device grouping;
+- ``group_rows_device`` / ``group_rows_host``: rows the engine grouped
+  with the GROUP BY key packed on the device / by the host's
+  ``group_key`` (:func:`grouped`);
 - ``lowered``: programs lowered (a shape new to the process), by the
   innermost span open on the lowering thread (``none`` outside any);
 - ``span_self_s``: host self seconds by span name.
@@ -37,7 +40,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-__all__ = ["SPANS", "span", "to_device", "to_host", "dispatch", "snapshot"]
+__all__ = [
+    "SPANS", "span", "to_device", "to_host", "dispatch", "grouped", "snapshot",
+]
 
 #: every span the program opens, with what it covers
 SPANS: Dict[str, str] = {
@@ -65,10 +70,11 @@ SPANS: Dict[str, str] = {
     "values (attrs rows)",
     "repro.engine.group": "a GROUP BY's ids and surviving key columns "
     "(attrs rows)",
-    "repro.engine.group_key": "the packed GROUP BY key on the host "
-    "(attrs rows)",
-    "repro.kernel.group_ids": "device grouping: key upload, sort passes, "
-    "run detection, order/start pull (attrs rows)",
+    "repro.engine.group_key": "the GROUP BY key's host work: the int64 "
+    "code (host grouping) or the word layout and column padding (device "
+    "grouping) (attrs rows)",
+    "repro.kernel.group_ids": "device grouping: key column upload, word "
+    "packing, sort passes, run detection, order/start pull (attrs rows)",
     "repro.kernel.segment_view": "one fused extend + GROUP BY node step "
     "(attrs rows, k, degree, groups)",
     "repro.kernel.segment_blocks": "one multi-block GROUP BY node step "
@@ -84,6 +90,7 @@ _LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _counter_lock = threading.Lock()
 _bytes = {"h2d_bytes": 0, "d2h_bytes": 0}
 _dispatches: Dict[str, int] = {}
+_grouped = {"group_rows_device": 0, "group_rows_host": 0}
 _lowered: Dict[str, int] = {}
 _span_ns: Dict[str, int] = {}
 _local = threading.local()
@@ -171,6 +178,13 @@ def dispatch(kernel: str) -> None:
         _dispatches[kernel] = _dispatches.get(kernel, 0) + 1
 
 
+def grouped(where: str, rows: int) -> None:
+    """Count ``rows`` grouped with the key packed on the ``"device"`` or
+    the ``"host"``."""
+    with _counter_lock:
+        _grouped[f"group_rows_{where}"] += rows
+
+
 def _on_duration(event: str, secs: float, **kw) -> None:
     if event != _LOWER:
         return
@@ -188,6 +202,7 @@ def snapshot() -> dict:
     with _counter_lock:
         return {
             **_bytes,
+            **_grouped,
             "dispatches": dict(_dispatches),
             "lowered": dict(_lowered),
             "span_self_s": {k: v * 1e-9 for k, v in _span_ns.items()},

@@ -12,6 +12,7 @@ import pytest
 from repro.kernels import ops, ref
 from repro.kernels.flash import flash_kernel_call
 from repro.kernels.gram import gram_kernel_call
+from repro.core.relation import group_key
 
 KEY = jax.random.key(42)
 
@@ -388,15 +389,36 @@ def test_segment_blocks_forced_chunking(impl):
     _assert_view_eq(chunked, ref.segment_blocks_ref(c, l, q, seg, g))
 
 
+def _host_groups(cols, doms):
+    """What the host path computes: ``np.unique`` over ``group_key``."""
+    key = group_key(cols, doms)
+    uniq, first, inv = np.unique(key, return_index=True, return_inverse=True)
+    return key, len(uniq), first, inv.astype(np.int32)
+
+
+def _assert_same_groups(cols, doms):
+    """Device grouping of ``cols`` equals the host path bit for bit: same
+    ids (groups in ascending tuple order), same stable order, the same
+    first occurrences and so the same key values at them."""
+    key, num, first, inv = _host_groups(cols, doms)
+    seg, dnum, dfirst, order = ops.group_ids_device(cols, doms)
+    assert dnum == num
+    np.testing.assert_array_equal(np.asarray(seg), inv)
+    np.testing.assert_array_equal(
+        np.asarray(order), np.argsort(key, kind="stable")
+    )
+    np.testing.assert_array_equal(dfirst, first)
+
+
 def test_group_ids_device_matches_np_unique():
-    """The device sort-based grouping is bit-compatible with the host
-    np.unique path: same segment ids, same group numbering (ascending key
-    order), same first-occurrence gather indices."""
+    """The device sort-based grouping of one key column is bit-compatible
+    with the host np.unique path: same segment ids, same group numbering
+    (ascending key order), same first-occurrence gather indices."""
     rng = np.random.default_rng(7)
     for n, dom in [(1, 1), (37, 5), (500, 40), (64, 64)]:
-        key = rng.integers(0, dom, n).astype(np.int64)
+        key = rng.integers(0, dom, n).astype(np.int32)
         uniq, first, inv = np.unique(key, return_index=True, return_inverse=True)
-        seg, num, dfirst, order = ops.group_ids_device(key)
+        seg, num, dfirst, order = ops.group_ids_device([key], [dom])
         assert num == len(uniq)
         np.testing.assert_array_equal(np.asarray(seg), inv.astype(np.int32))
         np.testing.assert_array_equal(
@@ -408,37 +430,105 @@ def test_group_ids_device_matches_np_unique():
 
 
 def test_group_ids_device_empty():
-    seg, num, first, order = ops.group_ids_device(np.zeros((0,), np.int64))
+    empty = np.zeros((0,), np.int32)
+    seg, num, first, order = ops.group_ids_device([empty, empty], [3, 4])
     assert num == 0 and seg.shape == (0,) and first.shape == (0,)
     assert order.shape == (0,)
 
 
 def test_group_ids_device_exact_for_wide_codes():
-    """Group codes past int32 (``relation.group_key`` packs several wide
-    attributes into int64) sort as two 32-bit halves: the grouping equals
-    ``np.unique`` exactly, and codes that differ only above bit 31 never
-    merge (``2**32 + 5`` and ``5`` are two groups)."""
+    """Keys whose packed code passes int32 (``a`` with 70,000 values and
+    ``b`` with 65,536 pack to ``a·65536 + b``) sort as two words: rows
+    whose codes differ only above bit 31 (``i`` and ``i + 65536``) stay
+    two groups, and the grouping equals ``np.unique`` exactly."""
+    n, nb = 70_000, 65_536
+    a = np.arange(n, dtype=np.int32)
+    b = (a % nb).astype(np.int32)
+    assert ops.word_layout([n, nb]) == (1, 1)
+    codes = group_key([a, b], [n, nb])
+    assert codes[nb] - codes[0] == 2**32  # equal once cut to int32
     rng = np.random.default_rng(11)
-    base = np.array(
-        [2**32 + 5, 5, 7, 2**31, 2**31 - 1, -3, 2**61 + 5, 2**61 + 2**32 + 5],
-        np.int64,
+    rows = rng.integers(0, n, 3000)
+    _assert_same_groups([a[rows], b[rows]], [n, nb])
+
+
+_I31 = 2**31 - 1
+
+
+@pytest.mark.parametrize(
+    "doms",
+    [
+        (1,),
+        (1, 1, 1),
+        (9,),
+        (1, 5, 1),
+        (3, 4, 5, 6, 7),
+        (46_341, 46_340),  # product 2,147,441,940: just below 2³¹−1
+        (_I31,),
+        (46_341, 46_341),  # product 2,147,488,281: just above
+        (2**31,),  # ids up to 2³¹−1, the padding rows' own value
+        (2**16, 2**16, 2**16),  # two int64 halves, three words
+        (2**20, 7, 2**20, 2**20, 2**20),  # past int64: group_key densifies
+        (2**31, 2**31, 2**31, 3),
+    ],
+)
+def test_group_ids_device_equals_group_key(doms):
+    """Device grouping over 1-5 key columns equals ``np.unique(group_key(
+    cols, doms))`` at every boundary of the word layout: domains of 1,
+    radix products at and around 2³¹−1, and products past int64."""
+    rng = np.random.default_rng(len(doms) * 131 + int(np.log2(max(doms))))
+    # a pool of tuples holding each column's extremes, drawn with repeats
+    pool = np.stack(
+        [
+            np.concatenate([[0, d - 1], rng.integers(0, d, 38)])
+            for d in doms
+        ],
+        axis=1,
     )
-    for key in (
-        base[:3],
-        np.concatenate(
-            [base, rng.choice(base, 50), rng.integers(0, 2**62, 200)]
-        ),
-    ):
-        uniq, first, inv = np.unique(
-            key, return_index=True, return_inverse=True
-        )
-        seg, num, dfirst, order = ops.group_ids_device(key)
-        assert num == len(uniq)
-        np.testing.assert_array_equal(np.asarray(seg), inv.astype(np.int32))
-        np.testing.assert_array_equal(
-            np.asarray(order), np.argsort(key, kind="stable")
-        )
-        np.testing.assert_array_equal(dfirst, first)
+    rows = pool[rng.integers(0, len(pool), 700)]
+    cols = [rows[:, j].astype(np.int32) for j in range(len(doms))]
+    _assert_same_groups(cols, list(doms))
+
+
+@pytest.mark.parametrize(
+    "doms, layout",
+    [
+        # Favorita's three 10M-row GROUP BYs (date, item_nbr, store_nbr,
+        # unit_sales): onpromotion, unit_sales and item_nbr nodes
+        ((1684, 4100, 54, 9_000_000), (3, 1)),
+        ((1684, 4100, 54), (3,)),
+        ((1684, 54), (2,)),
+        ((_I31,), (1,)),
+        ((2, _I31 // 2), (2,)),
+        ((2, _I31 // 2 + 1), (1, 1)),
+        ((2**16, 2**16, 2**16), (1, 1, 1)),
+        ((0, 1, 5), (3,)),
+    ],
+)
+def test_word_layout(doms, layout):
+    assert ops.word_layout(doms) == layout
+
+
+def test_word_layout_is_greedy_and_fits_int32():
+    """Over random domain lists: every word's radix product stays at or
+    below 2³¹−1 (a lone wider column aside), no word could take the next
+    word's first column, and a key whose product fits 31 bits is one
+    word."""
+    rng = np.random.default_rng(5)
+    for _ in range(300):
+        doms = [int(d) for d in 2 ** rng.uniform(0, 24, rng.integers(1, 7))]
+        layout = ops.word_layout(doms)
+        assert sum(layout) == len(doms)
+        words, i = [], 0
+        for width in layout:
+            words.append(doms[i : i + width])
+            i += width
+        for w, nxt in zip(words, words[1:] + [None]):
+            assert len(w) == 1 or np.prod(w, dtype=object) <= _I31
+            if nxt is not None:
+                assert np.prod(w + nxt[:1], dtype=object) > _I31
+        if np.prod(doms, dtype=object) <= _I31:
+            assert len(layout) == 1
 
 
 @pytest.mark.parametrize("degree", [1, 2])

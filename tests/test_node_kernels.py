@@ -142,6 +142,72 @@ def test_fused_device_grouping_matches_host():
     )
 
 
+def _favorita_grouped(monkeypatch, device: bool):
+    """Cofactors, a grouped view and the cofactors after a delta fold of
+    a tiny Favorita store, with device grouping on or off; also the
+    number of ``_merge_views`` folds and of rows each grouping path saw."""
+    from repro import obs
+    from repro.data.synthetic import favorita_like
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "fast_device_grouping", lambda: device)
+    merges = []
+    merge = FactorizedEngine._merge_views
+
+    def counted(self, a, b, degree):
+        merges.append(degree)
+        return merge(self, a, b, degree)
+
+    monkeypatch.setattr(FactorizedEngine, "_merge_views", counted)
+    b = favorita_like(n_dates=6, n_stores=3, n_items=4, seed=4)
+    cols = b.features + [b.label]
+    before = obs.snapshot()
+    eng = FactorizedEngine(b.store, b.vorder, cols, backend="jax")
+    assert eng.device_grouping == device
+    out = [eng.cofactors().matrix()]
+    grouped = FactorizedEngine(
+        b.store, b.vorder, ["onpromotion", b.label], backend="jax"
+    ).run_batch([AggregateQuery("g", ("date", "item_nbr", "store_nbr"), 2)])
+    out += [grouped["g"].count, grouped["g"].lin, grouped["g"].quad]
+    out += [grouped["g"].keys[a] for a in sorted(grouped["g"].keys)]
+    sales = b.store.get("SalesF")
+    rng = np.random.default_rng(9)
+    # few enough rows to fold, not compact (the store's compact_ratio)
+    keys = {a: rng.integers(0, int(sales.domains[a]), 8).astype(np.int32)
+            for a in sales.keys}
+    values = {a: rng.normal(0, 2.0, 8) for a in sales.values}
+    b.store.append("SalesF", Relation.from_columns("delta", keys, values))
+    b.store.flush()
+    out.append(
+        FactorizedEngine(b.store, b.vorder, cols, backend="jax")
+        .cofactors().matrix()
+    )
+    for _key, entry in sorted(b.store.view_cache.items(), key=repr):
+        view = entry.view  # every cached grouped view, folded or not
+        out += [view.keys[a] for a in view.keys]
+        out += [v for v in (view.c, view.l, view.q) if v is not None]
+    after = obs.snapshot()
+    rows = {w: after[f"group_rows_{w}"] - before[f"group_rows_{w}"]
+            for w in ("device", "host")}
+    return [np.asarray(o) for o in out], len(merges), rows
+
+
+def test_device_grouping_bit_identical_on_favorita(monkeypatch):
+    """Device grouping of the encoded key columns gives a Favorita-shaped
+    store the same cofactors, grouped views and delta-folded cofactors as
+    the host ``np.unique`` over ``group_key``, bit for bit, every fold
+    through ``_merge_views`` included."""
+    host, host_merges, host_rows = _favorita_grouped(monkeypatch, False)
+    dev, dev_merges, dev_rows = _favorita_grouped(monkeypatch, True)
+    assert host_merges == dev_merges > 0
+    assert host_rows["device"] == 0 < host_rows["host"]
+    assert dev_rows["host"] == 0 < dev_rows["device"]
+    assert len(host) == len(dev)
+    for h, d in zip(host, dev):
+        assert h.dtype == d.dtype
+        np.testing.assert_array_equal(h, d)
+
+
 def test_device_grouping_exact_for_wide_group_codes():
     """Forced device grouping over views whose GROUP BY codes pass 2³²:
     keys ``a`` (70,000 values) and ``b`` (65,536 values) pack to

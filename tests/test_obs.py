@@ -41,7 +41,7 @@ PARENTS = {
     "repro.engine.gather": {"repro.engine.node"},
     "repro.engine.feature": {"repro.engine.node"},
     "repro.engine.group": {"repro.engine.node"},
-    "repro.engine.group_key": {"repro.engine.group"},
+    "repro.engine.group_key": {"repro.engine.group", "repro.kernel.group_ids"},
     "repro.kernel.group_ids": {"repro.engine.group"},
     "repro.kernel.segment_view": {"repro.engine.node"},
     "repro.kernel.segment_blocks": {"repro.engine.node"},
@@ -181,14 +181,36 @@ def test_copy_counters_match_a_hand_sized_traversal():
     eng.cofactors()
     after = obs.snapshot()
     # per leaf: its feature column up (f32), its grouping key column up
-    # (int32, padded), the sort order (int32) and group starts (bool) down
+    # (int16, its domain fits; padded) with its int32 radix, the sort
+    # order (int32) and group starts (bool) down
     # node a: six takes (c, l, q per side) upload the join's int32 indices
     # and the one-group regroup uploads its int32 ids; then the root's
     # count, 2 sums and 2x2 products come down as f32
-    h2d = sum(4 * n + 4 * bucket(n, 1024) for n in (n_r, n_s)) + 7 * 4 * joined
+    h2d = sum(4 * n + 2 * bucket(n, 1024) + 4 for n in (n_r, n_s))
+    h2d += 7 * 4 * joined
     d2h = sum(5 * bucket(n, 1024) for n in (n_r, n_s)) + 4 * (1 + 2 + 4)
     assert after["h2d_bytes"] - before["h2d_bytes"] == h2d
     assert after["d2h_bytes"] - before["d2h_bytes"] == d2h
+
+
+@pytest.mark.parametrize("device", [True, False])
+def test_group_row_counters_count_each_path(device):
+    """``group_rows_device`` counts the rows grouped with the key packed on
+    the device, ``group_rows_host`` those ``group_key`` packed: each leaf
+    groups its rows by ``a`` once, and the root's empty GROUP BY packs no
+    key."""
+    store, vorder, _ = _two_relations()
+    rows = store.get("R").num_rows + store.get("S").num_rows
+    eng = FactorizedEngine(store, vorder, ["x", "y"], backend="jax",
+                           use_view_cache=False)
+    eng.device_grouping = device
+    before = obs.snapshot()
+    eng.cofactors()
+    after = obs.snapshot()
+    got = {w: after[f"group_rows_{w}"] - before[f"group_rows_{w}"]
+           for w in ("device", "host")}
+    assert got == ({"device": rows, "host": 0} if device
+                   else {"device": 0, "host": rows})
 
 
 def test_device_arrays_pass_through_uncounted():

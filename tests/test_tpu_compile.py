@@ -140,3 +140,15 @@ def test_gram_compiles(spec):
 
 def test_moments_compiles(spec):
     _compile(lambda x: ops.moments(x, interpret=False)[:2], spec((M,)))
+
+
+def test_pack_words_compiles(spec):
+    """Device grouping's word packing at Favorita's widest GROUP BY
+    (date, item_nbr, store_nbr as int16, unit_sales as int32; words of
+    three and one columns) over 2²⁴ rows: two int32 words out."""
+    size, i16 = 1 << 24, jnp.int16
+    cols = [spec((size,), i16)] * 3 + [spec((size,), jnp.int32)]
+    compiled = ops._pack_words.lower(
+        spec((), jnp.int32), spec((4,), jnp.int32), cols, layout=(3, 1)
+    ).compile()
+    assert compiled.memory_analysis().output_size_in_bytes >= 2 * 4 * size
