@@ -8,6 +8,9 @@ data for this file:
   after the last one returned;
 - ``feature_sets``: the feature list of each client's trains, by index
   modulo their number (absent: the configuration's own features);
+- ``train_args``: keyword arguments every train passes to
+  ``FactorizedService.train`` besides the configuration's ``ridge``, for
+  a kind of train the configuration needs (absent: none);
 - ``appends_per_train``: batches of the configuration's generator
   (``new_rows``: rows for one or more relations) each client sends before
   every train, each relation's append awaited until acknowledged;
@@ -78,7 +81,7 @@ class Driver:
         with TraceAnnotation("client.train"):
             ticket = self.svc.train(
                 tenant, self.vorder, self.features(client), self.cfg["label"],
-                ridge=self.cfg["ridge"],
+                ridge=self.cfg["ridge"], **self.mix.get("train_args", {}),
             )
             res = ticket.result(timeout=TIMEOUT_S)
         rec["done"] = time.perf_counter()

@@ -67,7 +67,7 @@ class Hooks:
         self.lock = threading.Lock()
         self.nodes = []  # node-step shapes, in call order
         self.calls = {}  # span -> calls
-        self.counts = {}  # id(ticket) -> cofactor count a train returned
+        self.counts = {}  # id(ticket) -> joined rows a read counted
         self.engines = []  # (use_node_kernels, device_grouping) per engine
 
     def _patch(self, module: str, path: str, make):
@@ -96,14 +96,17 @@ class Hooks:
         return self
 
     def results(self, service) -> "Hooks":
-        """Record the cofactor count behind every train ``service`` serves,
-        and the kernel flags of every engine built."""
+        """Record the count behind every read ``service`` serves, whatever
+        its kind: the count of its batch's ungrouped block (no group keys,
+        one group; a train's ``"cof"``), the joined rows it read.  Record
+        too the kernel flags of every engine built."""
 
         def finish(fn):
             def wrapped(read, blocks):
-                if read.kind == "train":
+                whole = [b for b in blocks.values() if not b.keys and b.num_groups == 1]
+                if whole:
                     with self.lock:
-                        self.counts[id(read.ticket)] = float(blocks["cof"].count[0])
+                        self.counts[id(read.ticket)] = float(whole[0].count[0])
                 return fn(read, blocks)
 
             return wrapped

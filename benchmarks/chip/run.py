@@ -15,7 +15,9 @@ of its own under this directory:
   compares its answers (``checks/<check>.py``);
 - ``endtoend/<metric>.py`` and ``metrics/<metric>.py`` one reader per
   metric;
-- ``limits/<cell>.json`` the limits of the numbers ``correct`` compares.
+- ``limits/<cell>.json`` the limits of the numbers ``correct`` compares;
+- ``tests/rehearsal/<config>.json`` the tiny sizes the CPU tests lay over
+  the configuration's (a run here never reads them).
 
 Set-up generates the data from ``--seed``, builds the ``Store`` and a
 threaded ``FactorizedService(backend="jax")`` and runs the mix's warm

@@ -1,6 +1,6 @@
 """Shared set-up of the benchmark's CPU tests: the harness module, steered
 onto the node kernels (Pallas in interpret mode) and device grouping, and
-the configurations at tiny sizes."""
+the configurations at the tiny sizes of ``rehearsal/<config>.json``."""
 
 import json
 import os
@@ -9,11 +9,6 @@ import pytest
 
 from benchmarks.chip import run as harness
 
-TINY = {
-    "favorita": {"n_dates": 8, "n_stores": 3, "n_items": 10, "fact_rows": 120,
-                 "append_rows_per_step": 4},
-    "tpch_sf1": {"scale_factor": 0.001},
-}
 SEED = 2**31 + 12345  # larger than 32 signed bits hold
 
 
@@ -22,13 +17,21 @@ def bench():
         return json.load(f)
 
 
+CELLS = [c["name"] for c in bench()["workloads"]]
+
+
 def plan(workload: str) -> dict:
     return harness.cell_plan(bench(), workload)
 
 
+def rehearsal(name: str) -> dict:
+    """The CPU rehearsal's sizes of the configuration ``name``."""
+    return harness.load_json("tests", "rehearsal", name + ".json")
+
+
 def tiny_config(name: str) -> dict:
-    cfg = harness.load_json(harness.ROOT, f"benchmarks/chip/configs/{name}.json")
-    cfg.update(TINY[name])
+    cfg = harness.load_json("configs", name + ".json")
+    cfg.update(rehearsal(name))
     return cfg
 
 
@@ -36,7 +39,7 @@ def tiny_config(name: str) -> dict:
 def steered(monkeypatch):
     """The harness as on the chip, but on the CPU and at tiny sizes: node
     kernels through Pallas (interpret mode), grouping through the device
-    path, configurations cut by ``TINY``."""
+    path, each configuration cut to its rehearsal sizes."""
     from repro.kernels import ops
 
     monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
@@ -46,7 +49,7 @@ def steered(monkeypatch):
     def load_json(*parts):
         d = orig(*parts)
         if parts[-1].startswith("benchmarks/chip/configs/"):
-            d.update(TINY[d["name"]])
+            d.update(rehearsal(d["name"]))
         return d
 
     monkeypatch.setattr(harness, "load_json", load_json)

@@ -3,7 +3,6 @@ through the threaded ``FactorizedService``, the comparison with the fp64
 reference, and the metric arithmetic; then the control and the faults the
 comparison has to catch."""
 
-import contextlib
 import json
 import os
 import subprocess
@@ -15,9 +14,7 @@ import numpy as np
 import pytest
 
 from benchmarks.chip import control
-from benchmarks.chip.tests.conftest import SEED, bench, plan
-
-CELLS = [c["name"] for c in bench()["workloads"]]
+from benchmarks.chip.tests.conftest import CELLS, SEED, plan
 
 
 def run_cell(harness, workload, seconds=1.5, trace=0, seed=SEED):
@@ -25,23 +22,36 @@ def run_cell(harness, workload, seconds=1.5, trace=0, seed=SEED):
     return harness.run_cell(args, jax.devices(), plan(workload))
 
 
+def metric_names(workload, kind, sources):
+    """The cell's ``kind`` metrics (``"end_to_end"`` or ``"per_layer"``)
+    whose source is one of ``sources``, by ``BENCHMARK.json``."""
+    return {m["name"] for m in plan(workload)[kind] if m["source"] in sources}
+
+
+#: what a CPU rehearsal can read: no device plane in its trace
+ON_THE_HOST = ("host_clock", "program_counter", "program_span")
+
+
 @pytest.mark.parametrize("workload", CELLS)
 def test_cell_matches_reference(steered, workload):
     out = run_cell(steered, workload)
     assert out["correct"], out["checks"]
-    assert out["checks"]["theta_gap"]["value"] < 1e-4
-    assert out["checks"]["count_gap"]["value"] == 0
+    limits = steered.load_json("limits", workload + ".json")
+    assert set(out["checks"]) == set(limits)
+    for name, c in out["checks"].items():
+        assert c["value"] <= c["limit"] == limits[name]["limit"], name
     assert out["attempted"] > 0 and out["failed"] == 0
     assert list(out)[-1] == "checks"
     assert "setup_s" in out["metrics"]
-    assert set(out["metrics"]) == {"setup_s", "train_rows_per_s"}
+    assert set(out["metrics"]) == metric_names(workload, "end_to_end", ON_THE_HOST)
 
 
-def test_traced_run_reports_per_layer_metrics_it_can_read(steered):
-    out = run_cell(steered, "favorita.fit", trace=1)
+@pytest.mark.parametrize("workload", CELLS)
+def test_traced_run_reports_per_layer_metrics_it_can_read(steered, workload):
+    out = run_cell(steered, workload, trace=1)
     assert out["correct"]
     # a CPU trace has no device plane: device metrics are left out, never 0
-    assert out["metrics"] == {}
+    assert not set(out["metrics"]) & metric_names(workload, "per_layer", ("device_trace",))
     assert {"busy_s", "window_s"} <= set(out["device"])
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
 
@@ -54,27 +64,30 @@ def test_control_is_not_correct(steered):
     assert res["checks"]["theta_gap"]["value"] > res["checks"]["theta_gap"]["limit"]
 
 
-def _altered_theta(monkeypatch):
-    from repro.serve import factorized
+def _altered_answer(monkeypatch, cfg):
+    """θ[1] × 1.01 in every answer the service hands back with a θ."""
+    from repro.serve.factorized import FactorizedService
 
-    orig = factorized.rescale_theta
+    orig = FactorizedService._finish
 
-    def altered(theta_conv, factors, mode="exact"):
-        theta = orig(theta_conv, factors, mode)
-        theta[1] *= 1.01
-        return theta
+    def altered(self, read, blocks):
+        answer = orig(self, read, blocks)
+        if getattr(answer, "theta", None) is not None:
+            answer.theta[1] *= 1.01
+        return answer
 
-    monkeypatch.setattr(factorized, "rescale_theta", altered)
+    monkeypatch.setattr(FactorizedService, "_finish", altered)
 
 
-def _half_the_rows(monkeypatch):
+def _half_the_rows(monkeypatch, cfg):
+    """Every other row of the configuration's fact relation left out."""
     from repro.core import factorize
 
     orig = factorize.FactorizedEngine._leaf_view
 
     def half(self, rel_name, degree):
         view = orig(self, rel_name, degree)
-        if rel_name == "SalesF":
+        if rel_name == cfg["fact"]:
             keep = (np.arange(view.num_rows) % 2 == 0).astype(np.float32)
             view = factorize._View(keys=view.keys, c=view.c * keep, l=view.l,
                                    q=view.q, feats=view.feats, degree=view.degree)
@@ -83,18 +96,23 @@ def _half_the_rows(monkeypatch):
     monkeypatch.setattr(factorize.FactorizedEngine, "_leaf_view", half)
 
 
+#: faults planted under the timed path, each taking the cell's configuration
 FAULTS = {
-    "answer_altered": (_altered_theta, CELLS),
-    "half_the_rows": (_half_the_rows, CELLS),
+    "answer_altered": _altered_answer,
+    "half_the_rows": _half_the_rows,
 }
 
 
-@pytest.mark.parametrize(
-    "fault,workload",
-    [(f, w) for f, (_, cells) in FAULTS.items() for w in cells],
-)
+def plant(harness, monkeypatch, fault, workload):
+    """Plant ``fault`` for a run of ``workload``, before its service is
+    built."""
+    cfg = harness.load_json(harness.ROOT, plan(workload)["config"]["file"])
+    FAULTS[fault](monkeypatch, cfg)
+
+
+@pytest.mark.parametrize("fault,workload", [(f, w) for f in FAULTS for w in CELLS])
 def test_fault_is_not_correct(steered, monkeypatch, fault, workload):
-    FAULTS[fault][0](monkeypatch)
+    plant(steered, monkeypatch, fault, workload)
     out = run_cell(steered, workload)
     assert not out["correct"], out["checks"]
 

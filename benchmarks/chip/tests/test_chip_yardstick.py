@@ -10,7 +10,7 @@ import pytest
 
 from benchmarks.chip import peaks, work
 from benchmarks.chip.configs import favorita
-from benchmarks.chip.tests.conftest import SEED, bench, tiny_config
+from benchmarks.chip.tests.conftest import SEED, tiny_config
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -101,28 +101,49 @@ def test_reference_reads_appends_to_every_relation():
     ]
 
 
-def test_benchmark_json_names_files_that_exist():
-    b = bench()
+def check_benchmark_files(root: str) -> None:
+    """``BENCHMARK.json`` at ``root`` against the files it names under
+    ``root``'s benchmark directory: each piece a cell needs is there."""
+    here = os.path.join(root, "benchmarks", "chip")
+
+    def load(*parts):
+        with open(os.path.join(here, *parts)) as f:
+            return json.load(f)
+
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        b = json.load(f)
     assert set(b) == {"command", "paths", "run_seconds", "configs", "workloads",
                       "end_to_end", "per_layer"}
-    root = os.path.dirname(os.path.dirname(HERE))
     assert os.path.isfile(os.path.join(root, b["command"][1]))
     names = [m["name"] for m in b["end_to_end"] + b["per_layer"]]
     names += [c["name"] for c in b["configs"] + b["workloads"]]
     assert len(names) == len(set(names)) and all(NAME.match(n) for n in names)
     for c in b["configs"]:
-        cfg = json.load(open(os.path.join(root, c["file"])))
+        with open(os.path.join(root, c["file"])) as f:
+            cfg = json.load(f)
         assert cfg["name"] == c["name"] and set(c["reduced"]) == set(cfg["reduced"])
-        assert os.path.isfile(os.path.join(HERE, "configs", c["name"] + ".py"))
+        assert os.path.isfile(os.path.join(here, "configs", c["name"] + ".py"))
+        # the CPU rehearsal's sizes: numbers laid over the configuration's own
+        sizes = load("tests", "rehearsal", c["name"] + ".json")
+        assert sizes and set(sizes) <= set(cfg), c["name"]
+        assert all(isinstance(v, (int, float)) for v in sizes.values()), c["name"]
     for w in b["workloads"]:
-        mix = json.load(open(os.path.join(HERE, "mixes", w["traffic"] + ".json")))
-        assert os.path.isfile(os.path.join(HERE, "drivers", mix["driver"] + ".py"))
-        assert os.path.isfile(os.path.join(HERE, "checks", mix["check"] + ".py"))
-        limits = json.load(open(os.path.join(HERE, "limits", w["name"] + ".json")))
-        assert set(limits) == {"theta_gap", "count_gap", "unanswered"}
+        mix = load("mixes", w["traffic"] + ".json")
+        assert os.path.isfile(os.path.join(here, "drivers", mix["driver"] + ".py"))
+        assert os.path.isfile(os.path.join(here, "checks", mix["check"] + ".py"))
+        assert isinstance(mix.get("train_args", {}), dict), w["traffic"]
+        # which numbers a check compares is held by the rehearsal
+        for number in load("limits", w["name"] + ".json").values():
+            assert isinstance(number["limit"], (int, float)) and number["from"]
+        assert any(m["name"] != "setup_s" and w["name"] in m.get("workloads", [w["name"]])
+                   for m in b["end_to_end"]), w["name"]
     for m in b["end_to_end"]:
-        assert os.path.isfile(os.path.join(HERE, "endtoend", m["name"] + ".py"))
+        assert os.path.isfile(os.path.join(here, "endtoend", m["name"] + ".py"))
     e2e = {m["name"] for m in b["end_to_end"]}
     for m in b["per_layer"]:
-        assert os.path.isfile(os.path.join(HERE, "metrics", m["name"] + ".py"))
+        assert os.path.isfile(os.path.join(here, "metrics", m["name"] + ".py"))
         assert m["moves"] in e2e
+
+
+def test_benchmark_json_names_files_that_exist():
+    check_benchmark_files(os.path.dirname(os.path.dirname(HERE)))
